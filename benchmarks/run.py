@@ -36,8 +36,10 @@ Prints ``name,us_per_call,derived`` CSV rows:
   roofline_*        — LM stack: dry-run-derived roofline summary per chosen
                       cell (reads results/dryrun; skips if absent)
 
-CPU wall-clock here characterizes the harness, not TPU performance; the TPU
-performance analysis lives in EXPERIMENTS.md §Roofline/§Perf.
+CPU wall-clock here characterizes the harness, not TPU performance; no row
+is a chip measurement (``chip_smoke.py`` is what runs the engine on a TPU).
+The subprocess phases run on placeholder CPU devices on every platform: a
+child never asks for the chip the parent holds.
 
 ``--only PREFIX[,PREFIX...]`` runs a subset (e.g. ``--only sweep`` for the
 CI sweep smoke step).
@@ -369,11 +371,12 @@ def run_sub_bench(code: str, prefix: str) -> None:
     fresh XLA) and collect its ``prefix``-named CSV rows."""
     env = dict(os.environ)
     env["PYTHONPATH"] = str(ROOT / "src")
+    # placeholder CPU devices on every platform: the parent holds the chip
+    env["JAX_PLATFORMS"] = "cpu"
     p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=1800, env=env)
     if p.returncode != 0:
-        emit(prefix + "error", 0.0, p.stderr.strip()[-120:])
-        return
+        raise RuntimeError(f"{prefix} phase failed:\n{p.stderr[-2000:]}")
     for line in p.stdout.strip().splitlines():
         if line.startswith(prefix):
             print(line)
@@ -561,7 +564,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import dataclasses, time, numpy as np, jax, jax.numpy as jnp
 from repro.core import DeltaConfig, Domain, Engine
 from repro.core.domain import spatial_axis_names
-from repro.core.engine import _shard_comm, shard_map_compat
+from repro.core.engine import _shard_comm
 from repro.core.grid import clear_ring
 from repro.core.halo import halo_exchange
 from repro.core.neighbors import sweep_accumulate
@@ -609,10 +612,12 @@ def interior_body(state):
                            beh.radius, beh.params, backend="tiled")
     return acc
 
-f_exch = jax.jit(shard_map_compat(
-    exch_body, mesh=mesh, in_specs=spec, out_specs=(spec, spec)))
-f_int = jax.jit(shard_map_compat(
-    interior_body, mesh=mesh, in_specs=spec, out_specs=spec))
+f_exch = jax.jit(jax.shard_map(
+    exch_body, mesh=mesh, in_specs=spec, out_specs=(spec, spec),
+    check_vma=False))
+f_int = jax.jit(jax.shard_map(
+    interior_body, mesh=mesh, in_specs=spec, out_specs=spec,
+    check_vma=False))
 
 def timeit(fn, n=10, warmup=2):
     for _ in range(warmup):
@@ -1028,7 +1033,10 @@ BENCHES = {
 
 
 def main(argv=None) -> None:
+    from repro.core.compile_cache import enable_persistent_cache
+
     argv = sys.argv[1:] if argv is None else argv
+    enable_persistent_cache()
     only = None
     if argv and argv[0] == "--only":
         if len(argv) < 2:

@@ -36,10 +36,7 @@ import jax.numpy as jnp
 
 from repro.analysis.diagnostics import Diagnostic
 
-try:  # jax >= 0.4.33
-    from jax.extend import core as jex_core
-except ImportError:  # pragma: no cover - older jax
-    import jax.core as jex_core
+from jax.extend import core as jex_core
 
 CONTRACT_COLLECTIVE = "collective-matching"
 CONTRACT_HOST_SYNC = "host-sync"

@@ -21,15 +21,27 @@ Two entry points:
 ``cache_stats()`` snapshots every registered cache; ``reset_stats()``
 zeroes the counters without dropping entries (benchmarks isolate phases
 with it).
+
+Across processes the executables live in JAX's persistent compilation
+cache, which :func:`enable_persistent_cache` places.  Entry points
+(``launch/simulate.py``, ``launch/serve.py``, ``benchmarks/run.py``,
+``chip_smoke.py``) call it before their first compile; importing the
+library never turns it on.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import os
+import pathlib
 import threading
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional, Tuple
+
+# <repo>/.jax_cache: fixed, so a later process finds what this one compiled
+# (the directory is part of the cache key; a moving one never hits).
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 _REGISTRY: "OrderedDict[str, CompiledCache]" = OrderedDict()
 _REGISTRY_LOCK = threading.Lock()
@@ -165,3 +177,21 @@ def reset_stats(prefix: str = "") -> None:
     for c in caches:
         if c.name.startswith(prefix):
             c.reset_stats()
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the place: JAX reads it
+    itself and no other path is set here.  Otherwise the cache goes to
+    ``<repo>/.jax_cache``.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_enable_compilation_cache", True)
+    return path

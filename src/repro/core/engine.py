@@ -51,7 +51,6 @@ from repro.core.halo import (
     ShardComm,
     halo_exchange,
     init_refs,
-    shard_map_compat,
     take_slab,
 )
 from repro.core.guards import (
@@ -177,6 +176,7 @@ class Engine:
         gid_counters: Optional[np.ndarray] = None,  # per-rank spawn floors
         it0: int = 0,                   # starting iteration counter
         base_key: Optional[np.ndarray] = None,      # (2,) uint32 RNG root
+        mesh=None,                      # spatial device mesh to place on
     ) -> SimState:
         """Distributed initialization (paper §2.4.4): agents are created
         directly on their authoritative device — no mass migration.
@@ -189,9 +189,20 @@ class Engine:
         id is ever issued twice, even across mesh-shape changes).  ``it0``
         seeds the iteration counter and ``base_key`` the RNG lineage: the
         per-device keys are split from ``fold_in(base_key, it0)`` rather
-        than a fresh ``PRNGKey(seed)``."""
+        than a fresh ``PRNGKey(seed)``.
+
+        Placement: on a multi-device geometry each device's block is binned
+        on that device and the global arrays are assembled from the shards
+        with the mesh's ``NamedSharding`` (``mesh``, else the default
+        spatial mesh), so no device ever holds the global state.  Only a
+        process with fewer devices than the geometry (a virtual split
+        traced for analysis) assembles the global arrays on one device."""
         geom = self.geom
         nd = geom.ndim
+        if mesh is None and geom.n_devices > 1 \
+                and jax.device_count() >= geom.n_devices:
+            mesh = _mesh_for(self)
+        dev_mesh = mesh
         mesh = geom.mesh_shape
         n_ranks = geom.n_devices
         schema = self.behavior.schema
@@ -262,6 +273,9 @@ class Engine:
         blocks: Dict[Tuple[int, ...], AgentSoA] = {}
         counters = np.zeros(mesh, dtype=np.int32)
         for coords in np.ndindex(*mesh):
+            # host arrays go straight to the block's own device, and the
+            # binning follows its committed inputs there
+            device = None if dev_mesh is None else dev_mesh.devices[coords]
             sel = np.ones(positions.shape[0], dtype=bool)
             for a in range(nd):
                 sel &= dev[a] == coords[a]
@@ -278,17 +292,17 @@ class Engine:
                     a = np.arange(n, dtype=np.int32)
                 else:
                     a = np.asarray(attrs[name][sel], dtype=dtype)
-                flat[name] = jnp.asarray(a)
-            valid = jnp.ones((n,), jnp.bool_)
+                flat[name] = jax.device_put(a, device)
+            valid = jax.device_put(np.ones((n,), np.bool_), device)
             if part is None:
-                origin = jnp.asarray(
+                origin = jax.device_put(np.asarray(
                     [coords[a] * lens[a] for a in range(nd)],
-                    dtype=jnp.float32)
+                    dtype=np.float32), device)
                 soa, dropped = bin_fn(flat, valid, origin)
             else:
-                origin = jnp.asarray(
+                origin = jax.device_put(np.asarray(
                     [origins[a][coords[a]] for a in range(nd)],
-                    dtype=jnp.float32)
+                    dtype=np.float32), device)
                 soa, dropped = bin_fn(
                     flat, valid, origin,
                     tuple(owned_w[a][coords[a]] for a in range(nd)))
@@ -299,17 +313,37 @@ class Engine:
                 )
             counters[coords] = max(
                 counters_next[lin], 0 if carried_gids else n)
+            if device is not None:
+                # an empty block's binning (zero-size inputs) carries no
+                # placement of its own
+                soa = jax.device_put(soa, device)
             blocks[coords] = soa
 
-        def blockcat(getter):
-            def rec(prefix: Tuple[int, ...]):
-                axis = len(prefix)
-                if axis == nd:
-                    return getter(blocks[prefix])
-                return jnp.concatenate(
-                    [rec(prefix + (i,)) for i in range(mesh[axis])],
-                    axis=axis)
-            return rec(())
+        if dev_mesh is None:
+            def blockcat(getter):
+                def rec(prefix: Tuple[int, ...]):
+                    axis = len(prefix)
+                    if axis == nd:
+                        return getter(blocks[prefix])
+                    return jnp.concatenate(
+                        [rec(prefix + (i,)) for i in range(mesh[axis])],
+                        axis=axis)
+                return rec(())
+
+            def per_device(x):
+                return jnp.asarray(x)
+        else:
+            sharding = _state_sharding(dev_mesh, nd)
+
+            def blockcat(getter):
+                shards = [getter(blocks[c]) for c in np.ndindex(*mesh)]
+                shape = tuple(m * h for m, h in zip(mesh, shards[0].shape)) \
+                    + shards[0].shape[nd:]
+                return jax.make_array_from_single_device_arrays(
+                    shape, sharding, shards)
+
+            def per_device(x):
+                return jax.device_put(np.asarray(x), sharding)
 
         first = blocks[(0,) * nd]
         attrs_g = {
@@ -320,7 +354,8 @@ class Engine:
 
         refs0 = init_refs(geom, first)
         refs_g = {
-            d: {f: _bcast(v, mesh) for f, v in slab.items()}
+            d: {f: per_device(np.broadcast_to(np.asarray(v), mesh + v.shape))
+                for f, v in slab.items()}
             for d, slab in refs0.items()
         }
 
@@ -335,13 +370,13 @@ class Engine:
         return SimState(
             soa=soa_g,
             refs=refs_g,
-            it=jnp.full(mesh, it0, jnp.int32),
-            key=keys,
-            gid_counter=jnp.asarray(counters),
-            dropped=jnp.zeros(mesh, jnp.int32),
-            halo_bytes=jnp.zeros(mesh, jnp.int32),
-            codec_overflow=jnp.zeros(mesh, jnp.int32),
-            health=jnp.zeros(mesh + (NUM_GUARDS,), jnp.int32),
+            it=per_device(np.full(mesh, it0, np.int32)),
+            key=per_device(keys),
+            gid_counter=per_device(counters),
+            dropped=per_device(np.zeros(mesh, np.int32)),
+            halo_bytes=per_device(np.zeros(mesh, np.int32)),
+            codec_overflow=per_device(np.zeros(mesh, np.int32)),
+            health=per_device(np.zeros(mesh + (NUM_GUARDS,), np.int32)),
         )
 
     # ------------------------------------------------------------------
@@ -701,6 +736,11 @@ class Engine:
         full refresh).  With delta encoding disabled every step is full
         and ``full_first`` is ignored.  ``n_steps`` is a *dynamic* loop
         bound — one executable covers every segment length.
+
+        ``seg.programs[full_first]`` is the jitted
+        ``(state, n_steps int32) -> state`` program itself, for lowering
+        and inspecting a compile (HLO, ``memory_analysis()``) for a
+        described device that holds no arrays.
         """
         if axis_names is None:
             axis_names = spatial_axis_names(self.geom.ndim)
@@ -879,13 +919,24 @@ def _mesh_for(engine: "Engine"):
     return make_abm_mesh(engine.geom.mesh_shape)
 
 
+def _state_sharding(mesh, ndim: int):
+    """The one sharding of every SimState leaf: leading grid/mesh dims
+    split over the spatial mesh axes."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return NamedSharding(mesh, P(*mesh.axis_names[:ndim]))
+
+
+# The per-step factories are one-step segments: a step and a fused
+# segment then run the same compiled loop body, so per-step and scan-fused
+# runs reduce in one order and agree bit for bit (XLA fuses a bare step
+# and a ``fori_loop`` body differently, which moves float sums by an ulp).
+
 @memoize("engine.local_step", maxsize=64)
 def _cached_local_step(engine: "Engine"):
-    comm = LocalComm(toroidal=engine.geom.toroidal)
+    seg = engine.make_segment_runner(None)
 
-    @partial(jax.jit, static_argnames=("full_halo",))
     def step(state: SimState, full_halo: bool = True) -> SimState:
-        return engine.local_step(state, comm, full_halo)
+        return seg(state, 1, full_first=full_halo)
 
     return step
 
@@ -906,22 +957,10 @@ def _shard_comm(engine: "Engine", axis_names: Tuple[str, ...]):
 @memoize("engine.sharded_step", maxsize=64)
 def _cached_sharded_step(engine: "Engine", mesh,
                          axis_names: Tuple[str, ...]):
-    comm, spec = _shard_comm(engine, axis_names)
-
-    def body(state: SimState, full_halo: bool) -> SimState:
-        return engine.local_step(state, comm, full_halo)
-
-    def make(full_halo: bool):
-        f = partial(body, full_halo=full_halo)
-        return jax.jit(
-            shard_map_compat(f, mesh=mesh, in_specs=spec, out_specs=spec)
-        )
-
-    step_full = make(True)
-    step_delta = make(False)
+    seg = engine.make_segment_runner(mesh, axis_names)
 
     def step(state: SimState, full_halo: bool = True) -> SimState:
-        return step_full(state) if full_halo else step_delta(state)
+        return seg(state, 1, full_first=full_halo)
 
     return step
 
@@ -940,9 +979,9 @@ def _cached_segment_runner(engine: "Engine", mesh,
 
         def wrap(full_first: bool):
             # n_steps rides along fully replicated (in_specs P()).
-            return jax.jit(shard_map_compat(
+            return jax.jit(jax.shard_map(
                 engine._segment_body(comm, full_first), mesh=mesh,
-                in_specs=(spec, P()), out_specs=spec))
+                in_specs=(spec, P()), out_specs=spec, check_vma=False))
 
         seg_t = wrap(True)
         seg_f = wrap(False)
@@ -952,6 +991,7 @@ def _cached_segment_runner(engine: "Engine", mesh,
         n = jnp.int32(n_steps)
         return seg_t(state, n) if full_first else seg_f(state, n)
 
+    seg.programs = {True: seg_t, False: seg_f}
     return seg
 
 

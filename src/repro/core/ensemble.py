@@ -53,7 +53,7 @@ from repro.core.delta import DeltaConfig
 from repro.core.domain import Domain, spatial_axis_names
 from repro.core.engine import Engine, SimState, _mesh_for
 from repro.core.guards import GUARD_CONSERVATION, GuardConfig, NUM_GUARDS
-from repro.core.halo import LocalComm, ShardComm, shard_map_compat
+from repro.core.halo import LocalComm, ShardComm
 
 Array = Any
 
@@ -270,10 +270,10 @@ class Ensemble:
                         lambda s, p: seg(s, p, n), in_axes=(0, 0)
                     )(states, params)
 
-                return jax.jit(shard_map_compat(
+                return jax.jit(jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(state_spec, param_spec, P()),
-                    out_specs=state_spec))
+                    out_specs=state_spec, check_vma=False))
 
         seg_t = wrap(True)
         seg_f = wrap(False)
@@ -283,6 +283,8 @@ class Ensemble:
             return seg_t(state, params, n) if full_first \
                 else seg_f(state, params, n)
 
+        # the jitted programs, for lowering against a described device
+        run.programs = {True: seg_t, False: seg_f}
         return run
 
     def make_runner(self, mesh=None):

@@ -93,6 +93,20 @@ def ravel_cells(geom: Domain, cells: Array) -> Array:
     return cid
 
 
+def run_ranks(sorted_key: Array, n_keys: int) -> Array:
+    """Rank of each element of an ascending key array (keys in
+    ``[0, n_keys)``) within its run of equal keys.
+
+    Counts per key and their exclusive prefix sum give each run's start.
+    The prefix sum runs over the keys, not the elements: a scan over
+    millions of slots (``associative_scan``/``cummax``) takes the TPU
+    compiler minutes, this takes seconds."""
+    counts = jnp.zeros((n_keys,), jnp.int32).at[sorted_key].add(1)
+    starts = jnp.cumsum(counts) - counts
+    idx = jnp.arange(sorted_key.shape[0], dtype=jnp.int32)
+    return idx - starts[sorted_key]
+
+
 def bin_agents(
     geom: Domain,
     attrs: Dict[str, Array],
@@ -112,7 +126,6 @@ def bin_agents(
     """
     shape = geom.local_shape
     cap = geom.cap
-    n = valid.shape[0]
 
     cell_id = ravel_cells(geom, cell_of(geom, attrs[POS], origin, owned))
     n_cells = math.prod(shape)
@@ -120,16 +133,7 @@ def bin_agents(
     key = jnp.where(valid, cell_id, n_cells)
     order = jnp.argsort(key, stable=True)
     sorted_key = key[order]
-
-    # Rank of each agent within its cell run.
-    idx = jnp.arange(n, dtype=jnp.int32)
-    is_start = jnp.concatenate(
-        [jnp.ones((1,), jnp.bool_), sorted_key[1:] != sorted_key[:-1]]
-    )
-    start_idx = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(is_start, idx, jnp.int32(-1))
-    )
-    rank = idx - start_idx
+    rank = run_ranks(sorted_key, n_cells + 1)   # slot within the cell
 
     ok = (sorted_key < n_cells) & (rank < cap)
     dropped = jnp.sum((sorted_key < n_cells) & (rank >= cap))

@@ -42,10 +42,6 @@ from repro.core.grid import ring_index
 
 Array = jax.Array
 
-# Re-exported for the engine and tests; the shim itself lives in the
-# layer-neutral repro.compat so the LM stack need not import ABM modules.
-from repro.compat import shard_map_compat  # noqa: E402,F401
-
 
 class Comm:
     """Spatial communication abstraction over an N-D device mesh."""

@@ -66,6 +66,8 @@ def resolve_sweep_backend(backend: str = "auto", ndim: int = 2) -> str:
     the fused Pallas kernel on TPU (2-D *and* 3-D domains — the kernel
     factory flattens cell blocks, so the ``3**ndim`` stencil only changes
     the neighborhood slab width), the tiled XLA sweep everywhere else.
+    ``auto`` names on TPU only a backend that ``chip_smoke.py`` ran there
+    and compared against ``reference``.
 
     ``ndim`` is kept for call-site compatibility: resolution has been
     dimension-independent since the factory gained 3-D blocks (it would

@@ -64,6 +64,7 @@ from repro.core.agent_soa import POS, AgentSoA
 from repro.core.compile_cache import memoize
 from repro.core.domain import Domain, Partition
 from repro.core.engine import Engine, SimState
+from repro.core.grid import run_ranks
 from repro.core.load_balance import (
     choose_partition,
     device_loads,
@@ -576,7 +577,6 @@ def _cached_device_migration(engine: Engine, new_geom: Domain):
         flats = {n: _interleave_flat(old, a)
                  for n, a in state.soa.attrs.items()}
         pos = flats[POS]
-        n = fvalid.shape[0]
 
         # 1. Route to the owning device of the new partition.
         dev = []
@@ -619,19 +619,11 @@ def _cached_device_migration(engine: Engine, new_geom: Domain):
         skey = jnp.where(fvalid, devlin * n_local + clocal, sentinel)
         order = jnp.argsort(skey, stable=True)
         sorted_key = skey[order]
-        idx = jnp.arange(n, dtype=jnp.int32)
-        is_start = jnp.concatenate(
-            [jnp.ones((1,), jnp.bool_),
-             sorted_key[1:] != sorted_key[:-1]])
-        # lax.cummax, NOT lax.associative_scan(jnp.maximum, ...): the
-        # generic scan's slice/concat decomposition miscompiles under
-        # GSPMD auto-partitioning (this function runs partitioned over the
-        # old mesh — unlike grid.bin_agents, whose identical idiom sits
-        # inside shard_map and never meets the partitioner).  cummax
-        # lowers to a dedicated op the partitioner handles correctly.
-        start_idx = jax.lax.cummax(
-            jnp.where(is_start, idx, jnp.int32(-1)))
-        rank = idx - start_idx
+        # the same run ranks grid.bin_agents computes inside shard_map;
+        # here GSPMD partitions them over the old mesh (which is why no
+        # lax.associative_scan appears: its slice/concat decomposition
+        # miscompiles under the partitioner)
+        rank = run_ranks(sorted_key, sentinel + 1)
         ok = (sorted_key < sentinel) & (rank < cap)
         n_dropped = jnp.sum((sorted_key < sentinel) & (rank >= cap))
 
@@ -731,23 +723,32 @@ def reshard_state_device(
     new_engine = dataclasses.replace(engine, geom=new_geom)
     mesh_to = new_geom.mesh_shape
     # Fresh zero aura references on the new geometry (the next step must
-    # run with full_halo=True, exactly like the host path).
-    from repro.core.engine import _bcast
+    # run with full_halo=True, exactly like the host path), each device's
+    # block placed on that device.
+    from repro.core.engine import _state_sharding
     from repro.core.halo import init_refs
+    from repro.launch.mesh import make_abm_mesh  # deferred: device state
     nd = new_geom.ndim
+    sharding = _state_sharding(make_abm_mesh(mesh_to), nd)
+
+    def per_device(x):
+        return jax.device_put(np.asarray(x), sharding)
+
     sample = AgentSoA(
         attrs={n: jnp.zeros(new_geom.local_shape + (new_geom.cap,)
                             + a.shape[nd + 1:], a.dtype)
                for n, a in new_soa.attrs.items()},
         valid=jnp.zeros(new_geom.local_shape + (new_geom.cap,), jnp.bool_))
     refs0 = init_refs(new_geom, sample)
-    refs = {d: {f: _bcast(v, mesh_to) for f, v in slab.items()}
+    refs = {d: {f: per_device(np.broadcast_to(np.asarray(v),
+                                              mesh_to + v.shape))
+                for f, v in slab.items()}
             for d, slab in refs0.items()}
     new_state = SimState(
         soa=new_soa, refs=refs, it=it, key=keys,
         gid_counter=counters, dropped=dropped,
-        halo_bytes=jnp.zeros(mesh_to, jnp.int32),
-        codec_overflow=jnp.zeros(mesh_to, jnp.int32),
+        halo_bytes=per_device(np.zeros(mesh_to, np.int32)),
+        codec_overflow=per_device(np.zeros(mesh_to, np.int32)),
         health=health)
     return new_engine, new_state
 
@@ -758,7 +759,7 @@ def reshard_state_device(
 
 def default_make_step(engine: Engine):
     """Step factory used after a re-shard: local step on a single-device
-    mesh, else a sharded step over a fresh version-compat spatial mesh."""
+    mesh, else a sharded step over a fresh spatial mesh."""
     if engine.geom.n_devices == 1:
         return engine.make_local_step()
     from repro.launch.mesh import make_abm_mesh  # deferred: device state

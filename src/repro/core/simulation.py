@@ -295,6 +295,8 @@ class Simulation:
              seed: int = 0, **kwargs) -> "Simulation":
         """Distributed initialization (Engine.init_state) through the
         facade; returns self for chaining."""
+        if self._mesh is not None and self.engine.geom.n_devices > 1:
+            kwargs.setdefault("mesh", self.mesh)
         self.state = self.engine.init_state(positions, attrs, seed=seed,
                                             **kwargs)
         self._step_fn = None

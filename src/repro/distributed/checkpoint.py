@@ -367,7 +367,9 @@ def restore(ckpt_dir: str, step: Optional[int] = None,
     assert len(leaves) == len(arrays), (
         f"checkpoint has {len(arrays)} leaves, tree expects {len(leaves)}")
     def cast(a, l):
-        return jax.numpy.asarray(a).astype(l.dtype)
+        # on the host: a sharded leaf goes from here straight to its
+        # devices, never whole onto the default one
+        return np.asarray(a).astype(l.dtype)
 
     if shardings is not None:
         shard_leaves = jax.tree_util.tree_leaves(
@@ -376,7 +378,8 @@ def restore(ckpt_dir: str, step: Optional[int] = None,
         placed = [jax.device_put(cast(a, l), s)
                   for a, l, s in zip(arrays, leaves, shard_leaves)]
     else:
-        placed = [cast(a, l) for a, l in zip(arrays, leaves)]
+        placed = [jax.numpy.asarray(cast(a, l))
+                  for a, l in zip(arrays, leaves)]
     return (manifest["step"],
             jax.tree_util.tree_unflatten(treedef, placed),
             manifest["extras"])

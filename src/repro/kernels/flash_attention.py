@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships the TPU compiler params under the TPU-prefixed name.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -110,7 +106,7 @@ def flash_attention_kernel(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hdv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,10 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Interpreter selection is automatic: kernels run through the Pallas
-interpreter on non-TPU backends (the CPU container) and compile to Mosaic
-on real TPU runtimes, keyed off ``jax.default_backend()``.  Both overrides
-survive: set ``repro.kernels.ops.INTERPRET`` to a bool to force the choice
-process-wide, or pass ``interpret=...`` to the wrappers that expose it.
+interpreter on the CPU platform and compile to Mosaic everywhere else,
+keyed off ``jax.default_backend()``.  ``repro.kernels.ops.INTERPRET`` (or
+``interpret=...`` on the wrappers that expose it) can force compiled
+Mosaic lowering on the CPU — how a compile for a described TPU is
+rehearsed — but can never put a TPU run in interpret mode.
 """
 
 from __future__ import annotations
@@ -17,19 +18,19 @@ import jax.numpy as jnp
 
 from repro.kernels import delta_codec, flash_attention, neighbor_interaction
 
-# None = auto-detect (interpret everywhere except on TPU); True/False force.
+# None = auto-detect (interpret on the CPU platform only); False forces
+# compiled lowering; True cannot interpret off the CPU platform.
 INTERPRET: Optional[bool] = None
 
 
 def use_interpret(override: Optional[bool] = None) -> bool:
     """Resolve the effective Pallas ``interpret`` flag: an explicit call-site
-    override wins, then the module-level ``INTERPRET`` force, then backend
-    auto-detection (compiled on TPU, interpreted elsewhere)."""
-    if override is not None:
-        return bool(override)
-    if INTERPRET is not None:
-        return bool(INTERPRET)
-    return jax.default_backend() != "tpu"
+    override wins, then the module-level ``INTERPRET`` force, then
+    auto-detection.  Interpretation happens only on the CPU platform: on a
+    TPU every kernel compiles, whatever was asked."""
+    want = override if override is not None else INTERPRET
+    on_cpu = jax.default_backend() == "cpu"
+    return on_cpu if want is None else bool(want) and on_cpu
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk"))

@@ -13,8 +13,6 @@ from typing import Optional, Tuple
 
 import jax
 
-from repro.compat import axis_types_kwargs as _axis_types_kwargs
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -24,13 +22,14 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh helper (tests, elastic re-shard, ABM spatial meshes)."""
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_abm_mesh(mesh_shape: Tuple[int, ...],
                   axes: Optional[Tuple[str, ...]] = None):
-    """Spatial device mesh for the ABM engine (paper Fig. 1 rank grid),
-    version-compat across JAX releases: ``(sx, sy)`` for 2-D domains,
+    """Spatial device mesh for the ABM engine (paper Fig. 1 rank grid):
+    ``(sx, sy)`` for 2-D domains,
     ``(sx, sy, sz)`` for 3-D ones.  The canonical way to build the mesh
     passed to ``Engine.make_sharded_step`` and the re-shard runtime."""
     mesh_shape = tuple(mesh_shape)

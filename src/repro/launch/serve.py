@@ -49,7 +49,7 @@ import numpy as np
 
 from repro.analysis import Diagnostic, check_ensemble
 from repro.core import operations
-from repro.core.compile_cache import cache_stats
+from repro.core.compile_cache import cache_stats, enable_persistent_cache
 from repro.core.ensemble import Ensemble
 
 
@@ -374,6 +374,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "step): 3 compatible requests batched into one "
                          "padded slot + 1 incompatible rejected")
     args = ap.parse_args(argv)
+    enable_persistent_cache()
     if args.smoke:
         return _smoke()
     ap.print_help()

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import DeltaConfig, Rebalance, total_agents
+from repro.core.compile_cache import enable_persistent_cache
 from repro.launch.mesh import make_abm_mesh
 
 SIMS = ["cell_clustering", "cell_proliferation", "epidemiology",
@@ -57,6 +58,7 @@ def main():
                          "(docs/performance.md); auto = tiled on CPU/GPU, "
                          "pallas on TPU")
     args = ap.parse_args()
+    enable_persistent_cache()
 
     import importlib
 

@@ -227,7 +227,6 @@ def moe_apply_ep(params, cfg: ArchConfig, x: Array) -> Tuple[Array, Array]:
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map_compat
     from repro.distributed import sharding as shlib
 
     active = getattr(shlib._ACTIVE, "v", None)
@@ -251,10 +250,10 @@ def moe_apply_ep(params, cfg: ArchConfig, x: Array) -> Tuple[Array, Array]:
             _moe_dense_decode_body, cfg=cfg, ep=ep, model_axis="model",
             fsdp_axes=fsdp_axes)
         spec = P(fsdp_axes, None, None)
-        return shard_map_compat(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec, P(None, None), w_spec, w_spec, w_spec),
-            out_specs=(spec, P()),
+            out_specs=(spec, P()), check_vma=False,
         )(x, params["router"], params["w_gate"], params["w_up"],
           params["w_down"])
 
@@ -263,10 +262,10 @@ def moe_apply_ep(params, cfg: ArchConfig, x: Array) -> Tuple[Array, Array]:
         model_axis="model")
     # tokens: batch over data axes, sequence over model — disjoint routing
     seq_spec = P(fsdp_axes, "model", None)
-    out = shard_map_compat(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(seq_spec, P(None, None), w_spec, w_spec, w_spec),
-        out_specs=(seq_spec, P()),
+        out_specs=(seq_spec, P()), check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"],
       params["w_down"])
     return out

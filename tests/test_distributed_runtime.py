@@ -119,8 +119,8 @@ from repro.launch.mesh import make_mesh
 mesh = make_mesh((4,), ("d",))
 def body(x):
     return compressed_psum(x[0], "d", axis_size=4)[None]
-from repro.compat import shard_map_compat
-f = jax.jit(shard_map_compat(body, mesh=mesh, in_specs=P("d"), out_specs=P("d")))
+f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("d"), out_specs=P("d"),
+                          check_vma=False))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 256))
 got = np.asarray(f(x))
 want = np.asarray(jnp.sum(x, axis=0))

@@ -412,8 +412,15 @@ assert sim.state.it.shape == sim.engine.geom.mesh_shape
 assert sim.n_agents() == n
 after = current_imbalance(sim.geom, sim.state)
 assert after * 2 <= before, (before, after)
+# Tolerance: a 2x2 split cannot be bit-exact with one device.  Migrants
+# re-bin after a cell's resident agents on their new device, where one
+# device orders a cell's slots by their old cell, so each agent's pair
+# sums add in another order.  Those ulp differences grow through the
+# soft-sphere dynamics (and flip adhesion/repulsion at overlap == 0):
+# 2.7e-4 after these 10 steps with jax 0.9.0 on CPU.  1e-3 bounds that,
+# and is 1/1000 of the agent diameter.
 err = np.max(np.abs(sorted_positions(s1.state) - sorted_positions(sim.state)))
-assert err < 1e-4, f"divergence {err}"
+assert err < 1e-3, f"divergence {err}"
 # facade keeps running on the new mesh without any caller-side fixup
 sim.run(3)
 assert sim.iteration == 13

@@ -203,7 +203,7 @@ def test_resolve_backend_3d_no_longer_falls_back():
         assert resolve_sweep_backend("auto", ndim=3) == "tiled"
 
 
-def test_resolve_backend_and_interpret_auto():
+def test_resolve_backend_and_interpret_auto(monkeypatch):
     from repro.kernels import ops
 
     # auto resolves per JAX backend (this container is CPU -> tiled,
@@ -224,6 +224,15 @@ def test_resolve_backend_and_interpret_auto():
         assert ops.use_interpret(True) is True
     finally:
         ops.INTERPRET = old
+    # per platform: pallas only on TPU, and a TPU run never interprets,
+    # whatever the call site or the module-level force asks
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    assert resolve_sweep_backend("auto") == "tiled"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_sweep_backend("auto") == "pallas"
+    monkeypatch.setattr(ops, "INTERPRET", True)
+    assert ops.use_interpret() is False
+    assert ops.use_interpret(True) is False
 
 
 # ---------------------------------------------------------------------------
