@@ -10,7 +10,7 @@ mesh.
   int8 delta-encoded aura exchange (paper §2.3).
 * **Device-to-device re-shard** — a skewed two-cluster density triggers
   one mid-run rebalance onto an uneven RCB partition, migrated by the
-  collective-permute fast path (``transport="device"``) with a deferred
+  device-to-device fast path (``transport="device"``) with a deferred
   (async-snapshot) plan: zero bytes through the host, asserted by
   trapping ``flatten_state``.
 

@@ -186,6 +186,11 @@ def enable_persistent_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR``, when set, is the place: JAX reads it
     itself and no other path is set here.  Otherwise the cache goes to
     ``<repo>/.jax_cache``.
+
+    A Pallas kernel's Mosaic body is part of the cache key and carries
+    the source locations of its trace, so source paths are cut to
+    ``repro/...`` (or the file's name): two checkouts of one tree then
+    share their entries.
     """
     import jax
 
@@ -193,5 +198,7 @@ def enable_persistent_cache() -> str:
     if not path:
         path = str(DEFAULT_CACHE_DIR)
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      r"^(.*/(?=repro/)|.*/)")
     jax.config.update("jax_enable_compilation_cache", True)
     return path

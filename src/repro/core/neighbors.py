@@ -96,7 +96,7 @@ def gather_neighborhood(geom: Domain, soa: AgentSoA, names: Tuple[str, ...]):
     nd = geom.ndim
     k = geom.cap
     offs = offsets_for(nd)
-    need = set(names) | {POS, GID_RANK, GID_COUNT}
+    need = sorted(set(names) | {POS, GID_RANK, GID_COUNT})
     isl = _interior(geom)
 
     def off_slice(off):
@@ -201,7 +201,7 @@ def pair_accumulate_tiled(
     """
     shape = geom.local_shape
     nd = geom.ndim
-    need = set(pair_attrs) | {POS, GID_RANK, GID_COUNT}
+    need = sorted(set(pair_attrs) | {POS, GID_RANK, GID_COUNT})
     isl = _interior(geom)
 
     # i views: (*interior, K, 1, t)

@@ -17,9 +17,10 @@ load balancing at *re-shard boundaries* (DESIGN note in core.load_balance):
    ``plan_diffusive`` as reported bounds) and triggers a re-shard.
 3. The mass migration is paid exactly once per re-shard.  On an unchanged
    device count ``reshard_state`` takes the *device-to-device* fast path
-   (:func:`reshard_state_device`): one compiled global re-bin whose outputs
-   are pinned to the new mesh, so XLA lowers the layout change to
-   collective permutes and no agent bytes ever cross the host boundary.
+   (:func:`reshard_state_device`): each device routes its agents to their
+   new owners in one ``all_to_all`` and bins what it receives, so no
+   device holds more than its share and no agent bytes cross the host
+   boundary.
    Otherwise (elastic restores, single-device geometries) the legacy host
    path runs: ``flatten_state`` gathers every live agent to host and
    ``reshard_state`` re-initializes through ``Engine.init_state``.  Both
@@ -478,202 +479,223 @@ def reshard_state(
 # 3b. Device-to-device mass migration (no host round trip)
 # ---------------------------------------------------------------------------
 
-def _interleave_flat(geom: Domain, a):
-    """Global sharded array -> flat per-slot view in the canonical
-    interleaved order (c0, i0, c1, i1, ..., slot) — the traced twin of
-    :func:`_interior_blocks` + ravel, so the device path enumerates agents
-    in exactly the order the host path does (slot assignment downstream is
-    order-dependent through the stable sort)."""
-    nd = geom.ndim
-    shape: Tuple[int, ...] = ()
-    for m, h in zip(geom.mesh_shape, geom.local_shape):
-        shape += (m, h)
-    a = a.reshape(shape + a.shape[nd:])
-    sl: Tuple = ()
-    for _ in range(nd):
-        sl += (slice(None), slice(1, -1))
-    a = a[sl]
-    return a.reshape((-1,) + a.shape[2 * nd + 1:])
+def _granule(n_slots: int) -> int:
+    """Rounding step of the per-destination buffer sizes: a 64th of a
+    device's interior slots (a power of two, at least 128), so that small
+    changes in the counts reuse one compiled migration."""
+    return max(128, 1 << max(0, (n_slots // 64 - 1).bit_length()))
 
 
-def _owned_flat_mask(geom: Domain) -> Optional[np.ndarray]:
-    """Static per-slot validity mask over the interleaved flat order for
-    uneven old geometries (padding + per-device aura ring excluded), or
-    None on the equal split (the interior slice already excludes the
-    ring)."""
-    if not geom.uneven:
-        return None
-    shape: Tuple[int, ...] = ()
-    for m, i in zip(geom.mesh_shape, geom.interior):
-        shape += (m, i)
-    mask = np.ones(shape, bool)
-    widths = geom.partition.widths
-    for a in range(geom.ndim):
-        for ci, w in enumerate(widths[a]):
-            sl = [slice(None)] * mask.ndim
-            sl[2 * a] = ci
-            sl[2 * a + 1] = slice(w, None)
-            mask[tuple(sl)] = False
-    return np.repeat(mask.ravel(), geom.cap)
+def _route_block(old: Domain, new_geom: Domain, pos):
+    """New linear rank of each agent, from its position: the routing
+    arithmetic ``Engine.init_state`` runs on host (equal-split floor-divide
+    or searchsorted partition cuts)."""
+    nd = old.ndim
+    cs = float(new_geom.cell_size)
+    mesh_to = new_geom.mesh_shape
+    part = new_geom.partition
+    rank = None
+    for a in range(nd):
+        if part is None:
+            d = jnp.floor_divide(
+                pos[:, a], jnp.float32(new_geom.interior[a] * cs)
+            ).astype(jnp.int32)
+        else:
+            cell = jnp.clip(
+                jnp.floor_divide(pos[:, a], jnp.float32(cs)).astype(jnp.int32),
+                0, new_geom.global_cells[a] - 1)
+            d = (jnp.searchsorted(jnp.asarray(np.asarray(part.cuts[a])),
+                                  cell, side="right") - 1).astype(jnp.int32)
+        d = jnp.clip(d, 0, mesh_to[a] - 1)
+        rank = d if rank is None else rank * mesh_to[a] + d
+    return rank
 
 
-@memoize("reshard.device_migration", maxsize=32)
-def _cached_device_migration(engine: Engine, new_geom: Domain):
-    """Compiled device-to-device migration: old-mesh sharded state in,
-    new-mesh sharded state out, agents never touching host.
+def _old_block_agents(old: Domain, soa: AgentSoA, coords):
+    """A device's owned slots in its local order, which is the canonical
+    order the host path enumerates (``flatten_state``): columns, validity
+    and each slot's index in that global order."""
+    nd = old.ndim
+    inner = (slice(1, -1),) * nd
+    flats = {n: a[inner].reshape((-1,) + a.shape[nd + 1:])
+             for n, a in soa.attrs.items()}
+    valid = soa.valid[inner]
+    # global interleaved slot index (c0, i0, c1, i1, ..., slot)
+    canon = jnp.zeros(valid.shape, jnp.int32)
+    for a in range(nd):
+        i = jax.lax.broadcasted_iota(jnp.int32, valid.shape, a)
+        canon = (canon * old.mesh_shape[a] + coords[a]) * old.interior[a] + i
+        if old.uneven:
+            w = jnp.asarray(np.asarray(old.partition.widths[a], np.int32))
+            valid = valid & (i < w[coords[a]])
+    canon = canon * old.cap + jax.lax.broadcasted_iota(
+        jnp.int32, valid.shape, nd)
+    return flats, valid.reshape(-1), canon.reshape(-1)
 
-    The body is the global generalization of ``grid.bin_agents``: flatten
-    every owned slot in the canonical interleaved order, route each agent
-    to its new device (equal-split floor-divide or searchsorted partition
-    cuts — the same arithmetic ``Engine.init_state`` runs on host), then
-    one stable argsort over the combined (device, local cell) key assigns
-    slots *identically* to the host path's per-device binning (the stable
-    global sort preserves original order within every (device, cell) run,
-    exactly like host-side selection followed by a per-device stable
-    sort).  ``out_shardings`` pins every output to the new mesh, so XLA
-    lowers the layout change to collective permutes of the per-device
-    shards.
-    """
-    from repro.launch.mesh import make_abm_mesh  # deferred: device state
-    from jax.sharding import NamedSharding, PartitionSpec as P
+
+def _mesh_positions(old_mesh, new_mesh) -> np.ndarray:
+    """``pos[r]``: the old mesh's linear position of the device that holds
+    new rank ``r``."""
+    where = {d.id: q for q, d in enumerate(old_mesh.devices.flat)}
+    return np.asarray([where[d.id] for d in new_mesh.devices.flat], np.int32)
+
+
+@memoize("reshard.migration_counts", maxsize=32)
+def _cached_migration_counts(engine: Engine, new_geom: Domain):
+    """Compiled count of the agents each device sends to each other
+    device: ``(*old_mesh, n)`` indexed by the receiver's old-mesh
+    position.  It sizes the migration's buffers."""
+    from jax.sharding import PartitionSpec as P
     from repro.core.domain import spatial_axis_names
+    from repro.launch.mesh import make_abm_mesh  # deferred: device state
 
     old = engine.geom
     nd = old.ndim
-    cap = old.cap
-    cs = float(new_geom.cell_size)
+    axes = spatial_axis_names(nd)
+    n = old.n_devices
+    old_mesh = make_abm_mesh(old.mesh_shape)
+    dest_pos = jnp.asarray(_mesh_positions(
+        old_mesh, make_abm_mesh(new_geom.mesh_shape)))
+
+    def body(soa: AgentSoA):
+        coords = [jax.lax.axis_index(ax) for ax in axes]
+        flats, valid, _ = _old_block_agents(old, soa, coords)
+        dq = dest_pos[_route_block(old, new_geom, flats[POS])]
+        counts = jnp.zeros((n,), jnp.int32).at[dq].add(
+            valid.astype(jnp.int32))
+        return counts.reshape((1,) * nd + (n,))
+
+    return jax.jit(jax.shard_map(body, mesh=old_mesh, in_specs=P(*axes),
+                                 out_specs=P(*axes)))
+
+
+@memoize("reshard.device_migration", maxsize=32)
+def _cached_device_migration(engine: Engine, new_geom: Domain,
+                             n_stay: int, n_send: int):
+    """Compiled device-to-device migration, run per device inside
+    ``shard_map`` over the old mesh: no device ever holds more than its
+    own block, its buffers and its new block.
+
+    Each device routes its owned agents to their new ranks, keeps the
+    ones that stay (at most ``n_stay``) and packs the rest into one
+    buffer of ``n_send`` agents per receiver; one ``all_to_all`` swaps
+    the buffers.  The device then orders what it holds by the canonical
+    global slot order of the old layout (the order ``flatten_state``
+    enumerates) and bins it with ``grid.bin_agents`` at its new rank's
+    origin, exactly as ``Engine.init_state`` bins the host path's
+    selection, so both paths place every agent in the same slot.  The
+    carry (spawn-counter floors, iteration, RNG lineage, drops) is reduced
+    over the mesh the way the host path reduces it.
+
+    Outputs are blocks of the new layout laid out on the old mesh; the
+    caller relabels them onto the new mesh's sharding (no data moves).
+    """
+    from jax.sharding import PartitionSpec as P
+    from repro.core.agent_soa import GID_COUNT, GID_RANK
+    from repro.core.domain import spatial_axis_names
+    from repro.core.grid import bin_agents
+    from repro.launch.mesh import make_abm_mesh  # deferred: device state
+
+    old = engine.geom
+    nd = old.ndim
+    axes = spatial_axis_names(nd)
+    n = old.n_devices
     mesh_to = new_geom.mesh_shape
-    lshape = new_geom.local_shape
-    n_ranks = new_geom.n_devices
+    old_mesh = make_abm_mesh(old.mesh_shape)
+    dest_np = _mesh_positions(old_mesh, make_abm_mesh(mesh_to))
+    dest_pos = jnp.asarray(dest_np)
+    rank_at = jnp.asarray(np.argsort(dest_np).astype(np.int32))
+    # each new rank's world origin and owned widths, as init_state has them
     part = new_geom.partition
-    # Static routing tables, computed with the same float64->float32
-    # rounding the host path uses.
+    ranks = list(np.ndindex(*mesh_to))
     if part is None:
-        lens = [i * cs for i in new_geom.interior]
-        origins = [
-            (np.arange(m, dtype=np.float64) * lens[a]).astype(np.float32)
-            for a, m in enumerate(mesh_to)]
-        cuts = owned_w = None
+        lens = [i * new_geom.cell_size for i in new_geom.interior]
+        origins = np.asarray([[c[a] * lens[a] for a in range(nd)]
+                              for c in ranks], np.float32)
+        owned = None
     else:
-        cuts = [np.asarray(part.cuts[a]) for a in range(nd)]
-        origins = [
-            (np.asarray(part.cuts[a][:-1], np.float64) * cs
-             ).astype(np.float32) for a in range(nd)]
-        owned_w = [np.asarray(part.widths[a], np.int32) for a in range(nd)]
-    old_mask = _owned_flat_mask(old)
-    n_local = math.prod(lshape)
-    total = n_local * n_ranks * cap
-    # Pin every output to the new mesh: the jit boundary then owes XLA a
-    # layout change from old-mesh to new-mesh shards, which GSPMD lowers
-    # to collective permutes — the "mass migration" without a host hop.
-    dev_mesh = make_abm_mesh(mesh_to)
-    out_sh = NamedSharding(dev_mesh, P(*spatial_axis_names(nd)))
-    rep_sh = NamedSharding(dev_mesh, P())
+        starts = [(np.asarray(part.cuts[a][:-1], np.float64)
+                   * new_geom.cell_size).astype(np.float32)
+                  for a in range(nd)]
+        origins = np.asarray([[starts[a][c[a]] for a in range(nd)]
+                              for c in ranks], np.float32)
+        owned = np.asarray([[part.widths[a][c[a]] for a in range(nd)]
+                            for c in ranks], np.int32)
+    big = np.iinfo(np.int32).max
 
-    def migrate(state: SimState):
-        fvalid = _interleave_flat(old, state.soa.valid)
-        if old_mask is not None:
-            fvalid = fvalid & jnp.asarray(old_mask)
-        flats = {n: _interleave_flat(old, a)
-                 for n, a in state.soa.attrs.items()}
-        pos = flats[POS]
+    def body(state: SimState):
+        coords = [jax.lax.axis_index(ax) for ax in axes]
+        me = jax.lax.axis_index(axes)
+        r = rank_at[me]
+        flats, valid, canon = _old_block_agents(old, state.soa, coords)
+        # one column per component: a TPU pads the minor dim of an (N, 2)
+        # gather or scatter to 128 lanes
+        cols = {(name, j): x.reshape(x.shape[0], -1)[:, j]
+                for name, x in flats.items()
+                for j in range(math.prod(x.shape[1:]))}
+        cols["canon"] = canon
 
-        # 1. Route to the owning device of the new partition.
-        dev = []
-        for a in range(nd):
-            if cuts is None:
-                d = jnp.floor_divide(
-                    pos[:, a], jnp.float32(lens[a])).astype(jnp.int32)
-            else:
-                cell = jnp.clip(
-                    jnp.floor_divide(
-                        pos[:, a], jnp.float32(cs)).astype(jnp.int32),
-                    0, new_geom.global_cells[a] - 1)
-                d = (jnp.searchsorted(
-                    jnp.asarray(cuts[a]), cell, side="right") - 1
-                ).astype(jnp.int32)
-            dev.append(jnp.clip(d, 0, mesh_to[a] - 1))
+        # 1. route, then pack: a stable sort by receiver keeps each
+        # receiver's agents in local (= canonical) order
+        dq = dest_pos[_route_block(old, new_geom, flats[POS])]
+        key = jnp.where(valid, dq, n)
+        order = jnp.argsort(key, stable=True)
+        sk = key[order]
+        rk = run_ranks(sk, n + 1)
+        stay = (sk == me) & (rk < n_stay)
+        send = (sk < n) & (sk != me) & (rk < n_send)
+        lost = jnp.sum((sk < n) & ~stay & ~send)
+        i_stay = jnp.where(stay, rk, n_stay)
+        i_send = jnp.where(send, sk * n_send + rk, n * n_send)
 
-        # 2. Local cell on that device (cell_of semantics incl. the halo
-        # offset and the uneven-ownership clamp).
-        origin = jnp.stack(
-            [jnp.asarray(origins[a])[dev[a]] for a in range(nd)], axis=1)
-        rel = (pos - origin) / jnp.float32(cs)
-        c = jnp.floor(rel).astype(jnp.int32) + 1
-        cell = []
-        for a in range(nd):
-            if owned_w is None:
-                cell.append(jnp.clip(c[:, a], 0, lshape[a] - 1))
-            else:
-                cell.append(jnp.clip(
-                    c[:, a], 0, jnp.asarray(owned_w[a])[dev[a]] + 1))
+        def pack(x, idx, size):
+            return jnp.zeros((size + 1,), x.dtype).at[idx].set(x)[:size]
 
-        # 3. One global stable sort over (device, local cell).
-        devlin = dev[0]
-        for a in range(1, nd):
-            devlin = devlin * mesh_to[a] + dev[a]
-        clocal = cell[0]
-        for a in range(1, nd):
-            clocal = clocal * lshape[a] + cell[a]
-        sentinel = n_ranks * n_local
-        skey = jnp.where(fvalid, devlin * n_local + clocal, sentinel)
-        order = jnp.argsort(skey, stable=True)
-        sorted_key = skey[order]
-        # the same run ranks grid.bin_agents computes inside shard_map;
-        # here GSPMD partitions them over the old mesh (which is why no
-        # lax.associative_scan appears: its slice/concat decomposition
-        # miscompiles under the partitioner)
-        rank = run_ranks(sorted_key, sentinel + 1)
-        ok = (sorted_key < sentinel) & (rank < cap)
-        n_dropped = jnp.sum((sorted_key < sentinel) & (rank >= cap))
+        def hold(x):
+            out = jax.lax.all_to_all(pack(x, i_send, n * n_send),
+                                     axes, 0, 0, tiled=True)
+            return jnp.concatenate([pack(x, i_stay, n_stay), out])
 
-        # 4. Scatter into the new global cell-slot grid.  Flat target
-        # index folds (dev, cell) straight into the global axes
-        # (global index along axis a = dev_a * h'_a + cell_a).
-        gidx = dev[0] * lshape[0] + cell[0]
-        for a in range(1, nd):
-            gidx = (gidx * (mesh_to[a] * lshape[a])
-                    + dev[a] * lshape[a] + cell[a])
-        slot = jnp.where(ok, gidx[order] * cap + rank, total)
-        gshape = tuple(m * h for m, h in zip(mesh_to, lshape))
-        new_attrs = {}
-        for name, a in flats.items():
-            src = a[order]
-            tgt = jnp.zeros((total + 1,) + a.shape[1:], a.dtype)
-            new_attrs[name] = tgt.at[slot].set(src)[:total].reshape(
-                gshape + (cap,) + a.shape[1:])
-        v = jnp.zeros((total + 1,), jnp.bool_).at[slot].set(ok)
-        new_soa = AgentSoA(attrs=new_attrs,
-                           valid=v[:total].reshape(gshape + (cap,)))
+        held = {name: hold(x[order]) for name, x in cols.items()}
+        hvalid = hold(stay | send)
 
-        # 5. Engine carry: spawn-counter floors (per-rank max carried id +
-        # the global floor max), iteration counter, RNG lineage, drops.
-        from repro.core.agent_soa import GID_COUNT, GID_RANK
-        g_rank = flats[GID_RANK]
-        g_count = flats[GID_COUNT]
-        in_range = fvalid & (g_rank >= 0) & (g_rank < n_ranks)
-        counters = jnp.zeros((n_ranks,), jnp.int32).at[
-            jnp.where(in_range, g_rank, 0)
-        ].max(jnp.where(in_range, g_count + 1, 0))
-        floor = jnp.max(state.gid_counter).astype(jnp.int32)
-        counters = jnp.maximum(counters, floor).reshape(mesh_to)
+        # 2. canonical order, then the host path's per-device binning
+        by = jnp.argsort(jnp.where(hvalid, held.pop("canon"), big))
+        attrs = {
+            name: jnp.stack([held[(name, j)][by]
+                             for j in range(math.prod(x.shape[1:]))],
+                            axis=-1).reshape((-1,) + x.shape[1:])
+            for name, x in flats.items()}
+        soa, dropped = bin_agents(
+            new_geom, attrs, hvalid[by], jnp.asarray(origins)[r],
+            None if owned is None else tuple(
+                jnp.asarray(owned)[r, a] for a in range(nd)))
+        n_dropped = jax.lax.psum(dropped + lost, axes)
 
-        it0 = jnp.max(state.it)
-        base_key = state.key[(0,) * nd].astype(jnp.uint32)
-        root = jax.random.fold_in(base_key, it0)
-        keys = jax.random.split(root, n_ranks).reshape(mesh_to + (-1,))
-        dropped = jnp.zeros(mesh_to, jnp.int32).at[(0,) * nd].add(
-            jnp.sum(state.dropped).astype(jnp.int32))
-        nguards = state.health.shape[-1]
-        out = (new_soa, counters, jnp.full(mesh_to, it0, jnp.int32),
-               keys, dropped,
-               jnp.zeros(mesh_to + (nguards,), jnp.int32))
-        out = jax.tree_util.tree_map(
-            lambda a: jax.lax.with_sharding_constraint(a, out_sh), out)
-        return out + (jax.lax.with_sharding_constraint(
-            n_dropped, rep_sh),)
+        # 3. carry: spawn-counter floors (per-rank max carried id + the
+        # global floor max), iteration counter, RNG lineage, drops
+        g_rank, g_count = flats[GID_RANK], flats[GID_COUNT]
+        ok = valid & (g_rank >= 0) & (g_rank < n)
+        floors = jnp.zeros((n,), jnp.int32).at[
+            jnp.where(ok, g_rank, 0)].max(jnp.where(ok, g_count + 1, 0))
+        floors = jax.lax.pmax(floors, axes)
+        floor = jax.lax.pmax(jnp.max(state.gid_counter), axes)
+        counter = jnp.maximum(floors[r], floor).astype(jnp.int32)
+        it0 = jax.lax.pmax(jnp.max(state.it), axes)
+        base_key = jax.lax.psum(jnp.where(
+            me == 0, state.key.reshape(-1), 0).astype(jnp.uint32), axes)
+        key_r = jax.random.split(jax.random.fold_in(base_key, it0), n)[r]
+        lost_before = jax.lax.psum(jnp.sum(state.dropped), axes)
+        one = (1,) * nd
+        carry = (counter.reshape(one), it0.astype(jnp.int32).reshape(one),
+                 key_r.reshape(one + (-1,)),
+                 jnp.where(r == 0, lost_before, 0).astype(
+                     jnp.int32).reshape(one))
+        return soa, carry, n_dropped
 
-    return jax.jit(migrate)
+    return jax.jit(jax.shard_map(
+        body, mesh=old_mesh, in_specs=P(*axes),
+        out_specs=(P(*axes), P(*axes), P())))
 
 
 def reshard_state_device(
@@ -681,17 +703,19 @@ def reshard_state_device(
     mesh_shape: Optional[Tuple[int, ...]] = None,
     partition: Optional[Partition] = None,
 ) -> Tuple[Engine, SimState]:
-    """Device-to-device mass migration: the collective-permute fast path
-    of :func:`reshard_state`.
+    """Device-to-device mass migration: the collective fast path of
+    :func:`reshard_state`.
 
-    Agents move directly between device shards inside one compiled
-    dispatch — ``flatten_state`` is never called and no agent bytes cross
-    the host boundary (the only host-visible scalar is the overflow-drop
-    diagnostic, which mirrors ``init_state``'s capacity check).  Requires
-    the device count to stay unchanged (elastic restores go through the
-    host path) and a multi-device geometry.  Bit-exact with the host
-    path: same routing arithmetic, same stable-sort slot assignment, same
-    carry (spawn floors, iteration, RNG lineage, cumulative drops).
+    Agents move directly between devices in one ``all_to_all`` inside one
+    compiled dispatch; ``flatten_state`` is never called and no agent
+    bytes cross the host boundary (the host reads only a device-by-device
+    count of movers, which sizes the buffers, and the overflow-drop
+    diagnostic, which mirrors ``init_state``'s capacity check).  No device
+    holds more than its own share of the state.  Requires the device count
+    to stay unchanged (elastic restores go through the host path) and a
+    multi-device geometry.  Bit-exact with the host path: same routing
+    arithmetic, same slot assignment, same carry (spawn floors, iteration,
+    RNG lineage, cumulative drops).
     """
     if (mesh_shape is None) == (partition is None):
         raise ValueError(
@@ -713,27 +737,51 @@ def reshard_state_device(
         raise ValueError(
             f"device path needs {new_geom.n_devices} devices, have "
             f"{jax.device_count()}; use the host path")
-    migrate = _cached_device_migration(engine, new_geom)
-    (new_soa, counters, it, keys, dropped, health,
-     n_dropped) = migrate(state)
+    old = engine.geom
+    n_slots = math.prod(old.interior) * old.cap
+    if n_slots * old.n_devices > np.iinfo(np.int32).max:
+        raise ValueError("device re-shard orders slots by int32 index; "
+                         "this state has too many slots")
+    counts = np.asarray(_cached_migration_counts(engine, new_geom)(
+        state.soa)).reshape(old.n_devices, old.n_devices)
+    moving = counts.copy()
+    np.fill_diagonal(moving, 0)
+    g = _granule(n_slots)
+
+    def size(c):
+        return min(n_slots, max(g, -(-int(c) // g) * g))
+
+    migrate = _cached_device_migration(
+        engine, new_geom, size(np.diag(counts).max()), size(moving.max()))
+    soa, (counters, it, keys, dropped), n_dropped = migrate(state)
     if int(n_dropped) != 0:
         raise ValueError(
             f"cell capacity overflow during device re-shard: "
             f"{int(n_dropped)} agents dropped; raise geom.cap")
     new_engine = dataclasses.replace(engine, geom=new_geom)
     mesh_to = new_geom.mesh_shape
-    # Fresh zero aura references on the new geometry (the next step must
-    # run with full_halo=True, exactly like the host path), each device's
-    # block placed on that device.
+    # The blocks sit on their devices already; only the global view
+    # changes from the old mesh's layout to the new one's.
     from repro.core.engine import _state_sharding
     from repro.core.halo import init_refs
     from repro.launch.mesh import make_abm_mesh  # deferred: device state
     nd = new_geom.ndim
     sharding = _state_sharding(make_abm_mesh(mesh_to), nd)
 
+    def relabel(x):
+        shards = [s.data for s in x.addressable_shards]
+        shape = tuple(m * h for m, h in zip(mesh_to, shards[0].shape)) \
+            + shards[0].shape[nd:]
+        return jax.make_array_from_single_device_arrays(
+            shape, sharding, shards)
+
     def per_device(x):
         return jax.device_put(np.asarray(x), sharding)
 
+    new_soa = jax.tree_util.tree_map(relabel, soa)
+    # Fresh zero aura references on the new geometry (the next step must
+    # run with full_halo=True, exactly like the host path), each device's
+    # block placed on that device.
     sample = AgentSoA(
         attrs={n: jnp.zeros(new_geom.local_shape + (new_geom.cap,)
                             + a.shape[nd + 1:], a.dtype)
@@ -745,11 +793,12 @@ def reshard_state_device(
                 for f, v in slab.items()}
             for d, slab in refs0.items()}
     new_state = SimState(
-        soa=new_soa, refs=refs, it=it, key=keys,
-        gid_counter=counters, dropped=dropped,
+        soa=new_soa, refs=refs, it=relabel(it), key=relabel(keys),
+        gid_counter=relabel(counters), dropped=relabel(dropped),
         halo_bytes=per_device(np.zeros(mesh_to, np.int32)),
         codec_overflow=per_device(np.zeros(mesh_to, np.int32)),
-        health=health)
+        health=per_device(np.zeros(mesh_to + (state.health.shape[-1],),
+                                   np.int32)))
     return new_engine, new_state
 
 

@@ -463,8 +463,9 @@ def test_overlapped_split_bitexact_3d_spheroid(backend):
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def run_sub(code: str, devices: int = 4, timeout: int = 1800) -> str:
-    env = dict(os.environ)
+def run_sub(code: str, devices: int = 4, timeout: int = 1800,
+            **env_extra: str) -> str:
+    env = dict(os.environ, **env_extra)
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}")
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
@@ -538,3 +539,27 @@ for backend in ("reference", "tiled", "pallas"):
 print("OK overlap sharded")
 """, devices=4)
     assert "OK overlap sharded" in out
+
+
+def test_step_program_is_independent_of_the_hash_seed():
+    """The compiled step is the persistent compile cache's key, so two
+    processes must lower one program to one text.  Iterating a ``set`` of
+    column names orders the sweep's ops by string hash, which differs from
+    process to process."""
+    code = """
+import hashlib
+from repro.sims import cell_clustering
+from repro.sims.common import make_sim
+from repro.analysis.jaxpr_audit import probe_state
+import jax, jax.numpy as jnp
+for backend in ("reference", "tiled"):
+    sim = make_sim(cell_clustering.behavior(), interior=(6, 6), cap=8,
+                   sweep_backend=backend)
+    state = jax.eval_shape(lambda: probe_state(sim.engine))
+    text = sim.engine.make_segment_runner(None).programs[True].lower(
+        state, jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+    print(backend, hashlib.sha256(text.encode()).hexdigest())
+"""
+    outs = [run_sub(code, devices=1, PYTHONHASHSEED=seed)
+            for seed in ("1", "2")]
+    assert outs[0] == outs[1], outs

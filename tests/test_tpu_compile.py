@@ -42,6 +42,9 @@ GRID = 256            # local runner: 256x256 interior cells, 1.57 M slots
 LOCAL_TEMP_BOUND = 1.07e9
 # 2x2 sharded runner, 128x128 cells per device: 276.9 MB per device.
 SHARDED_TEMP_BOUND = 0.31e9
+# Device re-shard of that state from 2x2 onto 1x4: 25.3 MB per device
+# (25,297,920 bytes) with buffers of 65,536 agents.
+MIGRATION_TEMP_BOUND = 0.028e9
 
 
 @pytest.fixture(scope="module")
@@ -186,3 +189,35 @@ def test_ensemble_runner_compiles(one_chip, tpu_auto):
     exe = ens.make_runner().programs[True].lower(state, params, n).compile()
     assert ("tpu_custom_call" in exe.as_text()) == \
         (ens.sweep_backend == "pallas")
+
+
+def test_device_migration_compiles(compiled, mesh_2x2, monkeypatch):
+    """The device-to-device re-shard of the 2x2 state onto a 1x4 cut: the
+    agents move in an all-to-all, nothing gathers the global state, and
+    each chip's temporaries stay under the whole state's bytes."""
+    from repro.core import Partition
+    from repro.core.domain import spatial_axis_names
+    from repro.core.reshard import _cached_device_migration
+    from repro.launch import mesh as launch_mesh
+
+    monkeypatch.setattr(
+        launch_mesh, "make_abm_mesh", lambda shape: Mesh(
+            np.array(compiled.devices).reshape(shape),
+            spatial_axis_names(len(shape))))
+    sim = make_sim(cell_clustering.behavior(), interior=(128, 128),
+                   mesh_shape=(2, 2), cap=24)
+    state = global_state_shapes(
+        sim.engine, NamedSharding(mesh_2x2, P("sx", "sy")))
+    whole = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    new_geom = sim.engine.geom.repartition(
+        Partition(cuts=((0, 256), (0, 64, 128, 192, 256))))
+    # the uncached factory: this module's meshes are of described devices
+    migrate = _cached_device_migration.__wrapped__(
+        sim.engine, new_geom, 65536, 65536)
+    exe = migrate.lower(state).compile()
+    text = exe.as_text()
+    assert "all-to-all" in text
+    assert "all-gather" not in text
+    temp = exe.memory_analysis().temp_size_in_bytes
+    assert temp < MIGRATION_TEMP_BOUND, temp
+    assert temp < whole, (temp, whole)
