@@ -15,11 +15,10 @@ Prints ``name,us_per_call,derived`` CSV rows:
                       takes 3-D blocks)
   halo_bytes_3d     — 3-D aura-exchange wire bytes/iter (6 directed edges),
                       full f32 vs int16 delta
-  halo_bytes_per_iter_* / overlap_efficiency / reshard_downtime_steps
+  halo_bytes_per_iter_* / reshard_downtime_steps
                     — communication budget (ROADMAP item 1,
                       docs/performance.md): per-sim steady-state aura wire
-                      bytes int8-compressed (R=16) vs raw, % of exchange
-                      wall time hidden behind the interior pass, re-shard
+                      bytes int8-compressed (R=16) vs raw, re-shard
                       downtime in steps host-path vs device-to-device
   sim_*             — paper Fig. 6 analogue: per-simulation iteration rate
                       (agent_updates/s, the Biocellion comparison metric
@@ -33,8 +32,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
                       imbalance before / after-equal / after-rcb (the
                       realized box-granular partition) vs the rcb_bound,
                       plus the padded-grid memory overhead
-  roofline_*        — LM stack: dry-run-derived roofline summary per chosen
-                      cell (reads results/dryrun; skips if absent)
 
 CPU wall-clock here characterizes the harness, not TPU performance; no row
 is a chip measurement (``chip_smoke.py`` is what runs the engine on a TPU).
@@ -515,9 +512,8 @@ report("tumor_spheroid", sim3.engine, sim3.state, sim3.n_agents())
 
 def bench_comm_budget():
     """Communication-budget rows: per-sim steady-state aura wire bytes
-    compressed (int8 delta, R=16) vs raw f32, the fraction of exchange
-    wall time the overlapped interior pass hides, and re-shard downtime
-    in steps for the host path vs the device-to-device collective."""
+    compressed (int8 delta, R=16) vs raw f32, and re-shard downtime in
+    steps for the host path vs the device-to-device collective."""
     from repro.core import DeltaConfig
     from repro.sims import (cell_clustering, cell_proliferation,
                             epidemiology, oncology)
@@ -556,99 +552,6 @@ def bench_comm_budget():
              f"_slab_reduction={raw / max(comp, 1):.2f}x"
              f"_float_payload_reduction={raw_f / max(comp_f, 1e-9):.2f}x"
              f"_amortized={raw / max(amort, 1e-9):.2f}x_at_R=16")
-
-    # --- overlap efficiency (subprocess: 2x2 placeholder mesh) ---------
-    code = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import dataclasses, time, numpy as np, jax, jax.numpy as jnp
-from repro.core import DeltaConfig, Domain, Engine
-from repro.core.domain import spatial_axis_names
-from repro.core.engine import _shard_comm
-from repro.core.grid import clear_ring
-from repro.core.halo import halo_exchange
-from repro.core.neighbors import sweep_accumulate
-from repro.launch.mesh import make_abm_mesh
-from repro.sims import cell_clustering
-
-beh = cell_clustering.behavior()
-geom = Domain(cell_size=2.0, interior=(8, 8), mesh_shape=(2, 2), cap=24)
-cfg = DeltaConfig(enabled=True, qdtype=jnp.int8, refresh_interval=16)
-eng = Engine(geom=geom, behavior=beh, delta_cfg=cfg, dt=0.1)
-rng = np.random.default_rng(0)
-n = 600
-pos = rng.uniform(0.5, 31.5, (n, 2)).astype(np.float32)
-attrs = {"diameter": np.full((n,), 1.0, np.float32),
-         "ctype": rng.integers(0, 2, n).astype(np.int32)}
-state = eng.init_state(pos, attrs, seed=0)
-mesh = make_abm_mesh((2, 2))
-axes = tuple(spatial_axis_names(2))
-comm, spec = _shard_comm(eng, axes)
-
-# a few real steps so the timed delta exchange runs against warm refs
-step = eng.make_sharded_step(mesh)
-state = step(state, full_halo=True)
-for _ in range(3):
-    state = step(state, full_halo=False)
-jax.block_until_ready(state.soa.valid)
-
-idx0 = (0, 0)
-
-def exch_body(state):
-    # the wire leg of local_step in isolation: ring invalidation, codec
-    # encode, ppermute per directed edge, codec decode, ring fill
-    refs = {d: {f: v[idx0] for f, v in slab.items()}
-            for d, slab in state.refs.items()}
-    soa_pre = clear_ring(state.soa)
-    soa2, _refs2, nb, _of = halo_exchange(
-        geom, soa_pre, comm, refs, cfg, False, None)
-    return soa2.valid, jnp.reshape(nb, (1, 1))
-
-def interior_body(state):
-    # the interior pass in isolation: the monolithic sweep on the
-    # ring-invalidated SoA (exactly what overlaps the exchange)
-    soa_pre = clear_ring(state.soa)
-    acc = sweep_accumulate(geom, soa_pre, beh.pair_fn, beh.pair_attrs,
-                           beh.radius, beh.params, backend="tiled")
-    return acc
-
-f_exch = jax.jit(jax.shard_map(
-    exch_body, mesh=mesh, in_specs=spec, out_specs=(spec, spec),
-    check_vma=False))
-f_int = jax.jit(jax.shard_map(
-    interior_body, mesh=mesh, in_specs=spec, out_specs=spec,
-    check_vma=False))
-
-def timeit(fn, n=10, warmup=2):
-    for _ in range(warmup):
-        jax.block_until_ready(fn(state))
-    t0 = time.perf_counter()
-    for _ in range(n):
-        jax.block_until_ready(fn(state))
-    return (time.perf_counter() - t0) / n * 1e6
-
-t_exch = timeit(f_exch)
-t_int = timeit(f_int)
-hidden = min(t_int, t_exch) / t_exch * 100.0
-
-def step_rate(overlap):
-    e = dataclasses.replace(eng, overlap=overlap)
-    st = e.make_sharded_step(mesh)(state, full_halo=True)
-    f = lambda: jax.block_until_ready(
-        e.make_sharded_step(mesh)(state, full_halo=False).soa.valid)
-    for _ in range(2):
-        f()
-    t0 = time.perf_counter()
-    for _ in range(6):
-        f()
-    return (time.perf_counter() - t0) / 6 * 1e6
-
-t_on, t_off = step_rate("on"), step_rate("off")
-print(f"overlap_efficiency,{t_exch:.1f},"
-      f"hidden={hidden:.0f}%_t_exchange={t_exch:.0f}us_t_interior={t_int:.0f}us"
-      f"_step_overlap_on={t_on:.0f}us_off={t_off:.0f}us")
-"""
-    run_sub_bench(code, "overlap_")
 
     # --- re-shard downtime: host vs device transport (subprocess) ------
     code = """
@@ -706,96 +609,6 @@ print(f"reshard_downtime_steps,{mig['device']*1e6:.1f},"
       f"_migration_host={mig['host']*1e6:.0f}us_device={mig['device']*1e6:.0f}us")
 """
     run_sub_bench(code, "reshard_downtime")
-
-
-# ---------------------------------------------------------------------------
-# Facade overhead: Simulation.run vs the raw Engine.drive loop
-# ---------------------------------------------------------------------------
-
-def bench_api_overhead():
-    """Driver dispatch cost: per-step dispatch vs the scan-fused segment
-    runner, and the Simulation facade vs the raw fused ``engine.drive``
-    (the facade must stay within noise — its work is pure Python
-    scheduling at segment boundaries)."""
-    import numpy as np
-
-    from repro.core import Engine, Domain, Simulation
-    from repro.sims import cell_clustering
-
-    beh = cell_clustering.behavior()
-    geom = Domain(cell_size=2.0, interior=(8, 8), mesh_shape=(1, 1),
-                    cap=24)
-    rng = np.random.default_rng(0)
-    n = 400
-    lx, ly = geom.domain_size
-    pos = rng.uniform(0.5, lx - 0.5, (n, 2)).astype(np.float32)
-    attrs = {"diameter": np.full((n,), 1.0, np.float32),
-             "ctype": rng.integers(0, 2, n).astype(np.int32)}
-    steps = 30
-
-    eng = Engine(geom=geom, behavior=beh, dt=0.1)
-    state0 = eng.init_state(pos, attrs, seed=0)
-    step = eng.make_local_step()
-
-    def time_per_step():
-        t0 = time.perf_counter()
-        _, s, _ = eng.drive(state0, steps, step_fn=step)
-        jax.block_until_ready(s.soa.valid)
-        return (time.perf_counter() - t0) / steps
-
-    def time_fused():
-        t0 = time.perf_counter()
-        _, s, _ = eng.drive(state0, steps)
-        jax.block_until_ready(s.soa.valid)
-        return (time.perf_counter() - t0) / steps
-
-    sim = Simulation(geom, beh, dt=0.1)
-
-    def time_facade():
-        sim.init(pos, attrs, seed=0)
-        t0 = time.perf_counter()
-        sim.run(steps)
-        jax.block_until_ready(sim.state.soa.valid)
-        return (time.perf_counter() - t0) / steps
-
-    time_per_step(), time_fused(), time_facade()           # warm compile
-    # interleave two passes each and keep the best: on shared CPU the
-    # scheduler noise exceeds the facade's pure-Python per-step cost
-    t_step = min(time_per_step(), time_per_step())
-    t_fuse = min(time_fused(), time_fused())
-    t_fac = min(time_facade(), time_facade())
-
-    emit("api_overhead_per_step_drive", t_step * 1e6,
-         f"agent_updates_per_s={n/t_step:.0f}_dispatch_per_step")
-    emit("api_overhead_raw_drive", t_fuse * 1e6,
-         f"agent_updates_per_s={n/t_fuse:.0f}"
-         f"_scan_fused_speedup={t_step/t_fuse:.1f}x")
-    emit("api_overhead_facade", t_fac * 1e6,
-         f"overhead={(t_fac/t_fuse - 1)*100:+.1f}%_vs_raw_drive")
-
-
-# ---------------------------------------------------------------------------
-# LM roofline summary (from dry-run records)
-# ---------------------------------------------------------------------------
-
-def bench_roofline():
-    d = ROOT / "results" / "dryrun"
-    if not d.exists():
-        emit("roofline_missing", 0.0, "run repro.launch.dryrun first")
-        return
-    best = {}
-    for p in sorted(d.glob("*__baseline.json")):
-        r = json.loads(p.read_text())
-        if r.get("status") != "ok":
-            continue
-        key = (r["arch"], r["shape"], r["mesh"])
-        best[key] = r
-    for (arch, shape, mesh), r in sorted(best.items()):
-        if mesh != "single":
-            continue
-        bound = max(r["t_compute_s"], r["t_memory_s"], r["t_collective_s"])
-        emit(f"roofline_{arch}_{shape}", bound * 1e6,
-             f"dominant={r['dominant']};frac={r['roofline_fraction']:.4f}")
 
 
 # ---------------------------------------------------------------------------
@@ -1022,13 +835,11 @@ BENCHES = {
     "comm_budget": bench_comm_budget,
     "sim": bench_sims,
     "sim_tumor_spheroid": bench_sim_tumor_spheroid,
-    "api_overhead": bench_api_overhead,
     "scaling": bench_scaling,
     "rebalance": bench_rebalance,
     "rebalance_uneven": bench_rebalance_uneven,
     "ensemble": bench_ensemble,
     "serve": bench_serve,
-    "roofline": bench_roofline,
 }
 
 

@@ -418,24 +418,26 @@ class Engine:
         # trips and lands in the health word at repack.
         gcfg = self.guards
         if gcfg.enabled:
-            own_cells = owned_mask(geom, owned) if owned is not None \
-                else jnp.asarray(interior_mask(geom))
-            g = jnp.zeros((NUM_GUARDS,), jnp.int32)
-            if gcfg.domain or gcfg.slab:
-                dom_bad, slab_bad = residency_counts(
-                    geom, soa, origin, own_cells)
-                if gcfg.domain:
-                    g = g.at[GUARD_DOMAIN].add(dom_bad)
-                if gcfg.slab:
-                    g = g.at[GUARD_SLAB].add(slab_bad)
+            with jax.named_scope("sim.guards"):
+                own_cells = owned_mask(geom, owned) if owned is not None \
+                    else jnp.asarray(interior_mask(geom))
+                g = jnp.zeros((NUM_GUARDS,), jnp.int32)
+                if gcfg.domain or gcfg.slab:
+                    dom_bad, slab_bad = residency_counts(
+                        geom, soa, origin, own_cells)
+                    if gcfg.domain:
+                        g = g.at[GUARD_DOMAIN].add(dom_bad)
+                    if gcfg.slab:
+                        g = g.at[GUARD_SLAB].add(slab_bad)
 
         # 1. Aura update (rebuilt from scratch each iteration, §2.2.1).
         # The pre-exchange SoA (ring invalidated) is kept alive: under the
         # overlapped sweep it is the interior pass's input buffer, so the
         # ppermute exchange below writes into what is effectively a double
         # buffer and nothing downstream of the interior pass waits on it.
-        soa_pre = clear_ring(soa) if owned is None \
-            else mask_unowned(soa, geom, owned)
+        with jax.named_scope("sim.aura"):
+            soa_pre = clear_ring(soa) if owned is None \
+                else mask_unowned(soa, geom, owned)
         soa, refs, hbytes, oflow = halo_exchange(
             geom, soa_pre, comm, refs, self.delta_cfg, full_halo, owned
         )
@@ -472,52 +474,57 @@ class Engine:
         # (at owned[a] + 1 <= interior[a]): those slots hold neighbor
         # copies and must not be updated as residents, so the validity is
         # masked down to the owned cells before the update runs.
-        isl = tuple(slice(1, h - 1) for h in shape)
-        int_attrs = {n: a[isl] for n, a in soa.attrs.items()}
-        int_valid = soa.valid[isl]
-        if owned is not None:
-            int_valid = int_valid & owned_mask(geom, owned)[isl][..., None]
-        step_key = jax.random.fold_in(jax.random.fold_in(key, it), lrank)
-        new_attrs, alive, spawn, child_attrs = beh.update_fn(
-            int_attrs, int_valid, acc, step_key, beh.params, self.dt
-        )
-        new_valid = int_valid & alive
+        with jax.named_scope("sim.update"):
+            isl = tuple(slice(1, h - 1) for h in shape)
+            int_attrs = {n: a[isl] for n, a in soa.attrs.items()}
+            int_valid = soa.valid[isl]
+            if owned is not None:
+                int_valid = int_valid \
+                    & owned_mask(geom, owned)[isl][..., None]
+            step_key = jax.random.fold_in(
+                jax.random.fold_in(key, it), lrank)
+            new_attrs, alive, spawn, child_attrs = beh.update_fn(
+                int_attrs, int_valid, acc, step_key, beh.params, self.dt
+            )
+            new_valid = int_valid & alive
 
-        # Per-axis boundary condition on positions: closed axes clamp
-        # (toroidal axes wrap inside the migration exchange).
-        lsz = jnp.asarray(geom.domain_size, jnp.float32)
-        if not all(tor):
-            eps = 1e-4 * geom.cell_size
-            lo = np.asarray([-np.inf if t else eps for t in tor],
-                            np.float32)
-            hi = np.asarray(
-                [np.inf if t else L - eps
-                 for t, L in zip(tor, geom.domain_size)], np.float32)
-            new_attrs[POS] = jnp.clip(new_attrs[POS], lo, hi)
+            # Per-axis boundary condition on positions: closed axes clamp
+            # (toroidal axes wrap inside the migration exchange).
+            lsz = jnp.asarray(geom.domain_size, jnp.float32)
+            if not all(tor):
+                eps = 1e-4 * geom.cell_size
+                lo = np.asarray([-np.inf if t else eps for t in tor],
+                                np.float32)
+                hi = np.asarray(
+                    [np.inf if t else L - eps
+                     for t, L in zip(tor, geom.domain_size)], np.float32)
+                new_attrs[POS] = jnp.clip(new_attrs[POS], lo, hi)
 
-        # 4. Flatten interior (+children) for re-binning.
-        n_int = math.prod(geom.interior) * k
-        flat = {n: a.reshape((n_int,) + a.shape[nd + 1:])
-                for n, a in new_attrs.items()}
-        fvalid = new_valid.reshape((n_int,))
+            # 4. Flatten interior (+children) for re-binning.
+            n_int = math.prod(geom.interior) * k
+            flat = {n: a.reshape((n_int,) + a.shape[nd + 1:])
+                    for n, a in new_attrs.items()}
+            fvalid = new_valid.reshape((n_int,))
 
-        if beh.can_spawn:
-            sflat = spawn.reshape((n_int,)) & fvalid
-            n_spawn = jnp.sum(sflat.astype(jnp.int32))
-            child = {n: a.reshape((n_int,) + a.shape[nd + 1:])
-                     for n, a in child_attrs.items()}
-            order = jnp.cumsum(sflat.astype(jnp.int32)) - 1
-            child[GID_RANK] = jnp.full((n_int,), lrank, jnp.int32)
-            child[GID_COUNT] = gidc + order
-            gidc = gidc + n_spawn
-            flat = {n: jnp.concatenate([flat[n], child[n]]) for n in flat}
-            fvalid = jnp.concatenate([fvalid, sflat])
+            if beh.can_spawn:
+                sflat = spawn.reshape((n_int,)) & fvalid
+                n_spawn = jnp.sum(sflat.astype(jnp.int32))
+                child = {n: a.reshape((n_int,) + a.shape[nd + 1:])
+                         for n, a in child_attrs.items()}
+                order = jnp.cumsum(sflat.astype(jnp.int32)) - 1
+                child[GID_RANK] = jnp.full((n_int,), lrank, jnp.int32)
+                child[GID_COUNT] = gidc + order
+                gidc = gidc + n_spawn
+                flat = {n: jnp.concatenate([flat[n], child[n]])
+                        for n in flat}
+                fvalid = jnp.concatenate([fvalid, sflat])
 
         # Conservation pre-count: every live agent (spawns included) about
         # to enter re-binning + migration, summed over the whole mesh.
         if gcfg.enabled and gcfg.conservation:
-            pre_n = comm.sum_over_all_ranks(
-                jnp.sum(fvalid, dtype=jnp.int32))
+            with jax.named_scope("sim.guards"):
+                pre_n = comm.sum_over_all_ranks(
+                    jnp.sum(fvalid, dtype=jnp.int32))
 
         soa2, d1 = bin_agents(geom, flat, fvalid, origin, owned)
         dropped = dropped + d1
@@ -533,13 +540,14 @@ class Engine:
         # than every other guard combined, and duplicates cannot
         # self-heal, so control-point granularity loses nothing.)
         if gcfg.enabled and gcfg.conservation:
-            live_owned = soa3.valid & own_cells[..., None]
-            post_n = comm.sum_over_all_ranks(
-                jnp.sum(live_owned, dtype=jnp.int32))
-            lost = comm.sum_over_all_ranks(
-                (d1 + d2).astype(jnp.int32))
-            g = g.at[GUARD_CONSERVATION].add(
-                jnp.abs(pre_n - post_n - lost))
+            with jax.named_scope("sim.guards"):
+                live_owned = soa3.valid & own_cells[..., None]
+                post_n = comm.sum_over_all_ranks(
+                    jnp.sum(live_owned, dtype=jnp.int32))
+                lost = comm.sum_over_all_ranks(
+                    (d1 + d2).astype(jnp.int32))
+                g = g.at[GUARD_CONSERVATION].add(
+                    jnp.abs(pre_n - post_n - lost))
 
         # 6. Repack per-device state.
         mesh = tuple(state.it.shape)
@@ -559,6 +567,7 @@ class Engine:
             health=_bcast(health + g if gcfg.enabled else health, mesh),
         )
 
+    @jax.named_scope("sim.migration")
     def _migrate(self, soa: AgentSoA, comm: Comm, origin: Array,
                  lsz: Array, owned=None
                  ) -> Tuple[AgentSoA, Array, Array]:
@@ -750,6 +759,11 @@ class Engine:
         """Per-device segment: first step optionally full, rest delta."""
         delta_on = self.delta_cfg.enabled
 
+        # Each layer of the step names its ops with a ``sim.*`` scope
+        # (docs/performance.md, "Reading a trace"); ``sim.carry`` holds the
+        # loop carry, the repack and whatever no inner scope claims.  The
+        # two programs get their own names, so a trace tells them apart.
+        @jax.named_scope("sim.carry")
         def seg(state: SimState, n_steps: Array) -> SimState:
             if not delta_on:
                 return jax.lax.fori_loop(
@@ -762,6 +776,7 @@ class Engine:
             return jax.lax.fori_loop(
                 0, rest, lambda i, s: self.local_step(s, comm, False), state)
 
+        seg.__name__ = "segment_full" if full_first else "segment_delta"
         return seg
 
     def drive(self, state: SimState, n_steps: int, step_fn=None,

@@ -107,6 +107,7 @@ def run_ranks(sorted_key: Array, n_keys: int) -> Array:
     return idx - starts[sorted_key]
 
 
+@jax.named_scope("sim.binning")
 def bin_agents(
     geom: Domain,
     attrs: Dict[str, Array],

@@ -120,6 +120,7 @@ def as_guard_config(guards) -> GuardConfig:
 # Traced reductions (called from Engine.local_step, per device)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("sim.guards")
 def nan_count(soa: AgentSoA) -> Array:
     """Live slots carrying a non-finite value in any float attribute."""
     total = jnp.int32(0)
@@ -134,6 +135,7 @@ def nan_count(soa: AgentSoA) -> Array:
     return total
 
 
+@jax.named_scope("sim.guards")
 def residency_counts(geom, soa: AgentSoA, origin: Array,
                      own_cells: Array) -> Tuple[Array, Array]:
     """(out_of_domain, out_of_slab) counts over live owned agents.

@@ -185,6 +185,7 @@ def _codec_recv(payload, ref, cfg: DeltaConfig, full: bool):
     return decode_delta(payload, ref, cfg)
 
 
+@jax.named_scope("sim.aura")
 def halo_exchange(
     geom: Domain,
     soa: AgentSoA,

@@ -290,6 +290,7 @@ def pair_accumulate_pallas(
             for n, a in acc.items()}
 
 
+@jax.named_scope("sim.sweep")
 def sweep_accumulate(
     geom: Domain,
     soa: AgentSoA,
@@ -391,6 +392,7 @@ def _face_sweep(
         vgeom, band, pair_fn, pair_attrs, radius, params, backend)
 
 
+@jax.named_scope("sim.sweep")
 def sweep_accumulate_overlapped(
     geom: Domain,
     soa_pre: AgentSoA,
@@ -423,26 +425,27 @@ def sweep_accumulate_overlapped(
     planes).
     """
     backend = resolve_sweep_backend(backend, geom.ndim)
-    acc = _sweep_dispatch(
-        geom, soa_pre, pair_fn, pair_attrs, radius, params, backend)
+    with jax.named_scope("sim.sweep.interior"):
+        acc = _sweep_dispatch(
+            geom, soa_pre, pair_fn, pair_attrs, radius, params, backend)
     nd = geom.ndim
-    for axis in range(nd):
-        lo = 1
-        hi = (geom.local_shape[axis] - 2 if owned is None
-              else jnp.asarray(owned[axis], jnp.int32))
-        faces = [lo, hi]
-        for face_idx in faces:
-            facc = _face_sweep(
-                geom, soa_post, pair_fn, pair_attrs, radius, params,
-                backend, axis, face_idx)
-            starts = [0] * nd
-            starts[axis] = (face_idx - 1 if isinstance(face_idx, int)
-                            else jnp.asarray(face_idx, jnp.int32) - 1)
-            new_acc = {}
-            for name, a in acc.items():
-                st = [jnp.asarray(s, jnp.int32) for s in starts]
-                st = st + [jnp.int32(0)] * (a.ndim - nd)
-                new_acc[name] = jax.lax.dynamic_update_slice(
-                    a, facc[name].astype(a.dtype), st)
-            acc = new_acc
+    with jax.named_scope("sim.sweep.faces"):
+        for axis in range(nd):
+            lo = 1
+            hi = (geom.local_shape[axis] - 2 if owned is None
+                  else jnp.asarray(owned[axis], jnp.int32))
+            for face_idx in (lo, hi):
+                facc = _face_sweep(
+                    geom, soa_post, pair_fn, pair_attrs, radius, params,
+                    backend, axis, face_idx)
+                starts = [0] * nd
+                starts[axis] = (face_idx - 1 if isinstance(face_idx, int)
+                                else jnp.asarray(face_idx, jnp.int32) - 1)
+                new_acc = {}
+                for name, a in acc.items():
+                    st = [jnp.asarray(s, jnp.int32) for s in starts]
+                    st = st + [jnp.int32(0)] * (a.ndim - nd)
+                    new_acc[name] = jax.lax.dynamic_update_slice(
+                        a, facc[name].astype(a.dtype), st)
+                acc = new_acc
     return acc

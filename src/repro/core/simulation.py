@@ -34,6 +34,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.behaviors import Behavior, compose
 from repro.core.delta import DeltaConfig
@@ -399,8 +400,12 @@ class Simulation:
         boundaries, rebalance checks) are fused into one compiled dispatch
         by the engine's segment runner; a per-step op (``every=1``) keeps
         the historical one-dispatch-per-step cadence.  ``fused=False``
-        forces one dispatch per step (overhead benchmarks pin the
-        dispatch cost with it).
+        forces one dispatch per step.
+
+        Each scheduled op, dispatch, wait for outputs and host-side check
+        is a ``sim.*`` span on the profiler's clock
+        (docs/performance.md, "Reading a trace"); the spans add no host
+        sync.
 
         ``fault_plan`` (distributed.chaos.FaultPlan) injects scheduled
         faults at their absolute iterations; segments break at pending
@@ -477,21 +482,29 @@ class Simulation:
             sample = (self._weighted and rb is not None and n == 1
                       and rb.due(tick + 1))
             t0 = time.perf_counter() if sample else 0.0
-            if per_step:
-                self.state = self._step_fn(self.state, full_halo=full)
-            else:
-                self.state = self._seg_fn(self.state, n, full_first=full)
+            with TraceAnnotation("sim.dispatch", steps=n, full=full):
+                if per_step:
+                    self.state = self._step_fn(self.state, full_halo=full)
+                else:
+                    self.state = self._seg_fn(self.state, n,
+                                              full_first=full)
+            if sample or track_clip or track_health:
+                # the host reads below wait for the outputs anyway; the
+                # span says how long, so they time only their own work
+                with TraceAnnotation("sim.wait"):
+                    jax.block_until_ready(self.state)
             if sample:
-                jax.block_until_ready(self.state.soa.valid)
                 self._last_step_s = time.perf_counter() - t0
             if track_clip:
-                cnt = codec_overflow_count(self.state)
+                with TraceAnnotation("sim.codec_check"):
+                    cnt = codec_overflow_count(self.state)
                 if cnt > clip_mark:
                     self._force_full = True
                     clip_mark = cnt
             if track_health:
-                hmark, _ = check_health(self.engine.guards, self.state,
-                                        hmark)
+                with TraceAnnotation("sim.guards.host_check"):
+                    hmark, _ = check_health(self.engine.guards, self.state,
+                                            hmark)
             for t in range(tick, tick + n):
                 for op in ops:
                     if not op.pre and op.due(t):
@@ -501,7 +514,8 @@ class Simulation:
         return self
 
     def _run_op(self, op: Operation) -> None:
-        value = op.fn(self)
+        with TraceAnnotation(f"sim.op.{op.name}"):
+            value = op.fn(self)
         if op.record and value is not None:
             self.series.setdefault(op.name, []).append(value)
 

@@ -252,6 +252,7 @@ def pair_sweep_kernel(
         in_specs=in_specs,
         out_specs=[plane_spec(k)] * len(out_shape),
         out_shape=out_shape,
+        name="sim_sweep_pairs",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_VMEM_LIMIT,

@@ -427,3 +427,64 @@ assert sim.iteration == 13
 print("OK", before, "->", after, "err", err)
 """)
     assert "OK" in out
+
+
+def _host_spans(trace_dir):
+    """(name, start_ns, end_ns) of every ``sim.*`` host span the profiler
+    recorded under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    return sorted(
+        (ev.name, ev.start_ns, ev.end_ns)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("sim."))
+
+
+def test_run_names_its_host_work_on_the_profilers_clock(tmp_path):
+    """With guards on, each dispatch, the wait for its outputs, the guards'
+    host check and each scheduled op is a span, and the check starts only
+    once the wait has ended."""
+    import jax
+
+    pos, attrs = make_inputs()
+    sim = Simulation(dict(interior=(8, 8), cap=24), make_behavior(), dt=0.1,
+                     guards="warn")
+    sim.init(pos, attrs, seed=0)
+    sim.every(2, operations.agent_count, name="count")
+    sim.run(1)                                   # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sim.run(4)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    names = [n for n, _, _ in spans]
+    n = names.count("sim.dispatch")             # one per fused segment
+    assert 2 <= n < 4
+    assert names.count("sim.wait") == n
+    assert names.count("sim.guards.host_check") == n
+    assert names.count("sim.op.count") == 2
+    waits = [(s, e) for n, s, e in spans if n == "sim.wait"]
+    checks = [(s, e) for n, s, e in spans if n == "sim.guards.host_check"]
+    for (_, wait_end), (check_start, _) in zip(waits, checks):
+        assert check_start >= wait_end
+
+
+def test_run_without_guards_or_codec_moves_nothing_to_the_host():
+    """The spans add no host sync: with guards and the delta codec off a
+    run never reads the device."""
+    import jax
+
+    pos, attrs = make_inputs()
+    sim = Simulation(dict(interior=(8, 8), cap=24), make_behavior(), dt=0.1)
+    sim.init(pos, attrs, seed=0)
+    sim.run(1)
+    with jax.transfer_guard_device_to_host("disallow"):
+        sim.run(3)
+        sim.run(2, fused=False)
