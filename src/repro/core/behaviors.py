@@ -22,8 +22,10 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.extend.random import threefry2x32_p
 
-from repro.core.agent_soa import AgentSchema, POS
+from repro.core.agent_soa import AgentSchema, GID_COUNT, GID_RANK, POS
 from repro.core.neighbors import PairFn
 
 Array = jax.Array
@@ -62,6 +64,14 @@ class Behavior:
     # contract checker and hot-path lint walk this to analyze leaf kernels
     # instead of the synthesized compose() wrappers.
     children: Tuple["Behavior", ...] = ()
+    # Which key the engine hands update_fn.  False: a per-device step key,
+    # ``fold_in(fold_in(device_key, t), rank)``, from which the update
+    # draws over the padded slot grid, so a draw follows the slot.  True:
+    # ``fold_in(root, t)`` with ``root`` the engine's root key, the same on
+    # every device, from which :func:`per_agent_keys` derives one key per
+    # agent from its global id: an agent's draws then do not depend on
+    # its slot, the cell capacity, the order of agents or the mesh.
+    agent_keys: bool = False
 
     # Behavior.stack(a, b, ...) — alias of compose(); bound as a class
     # attribute after compose() is defined below (not a dataclass field).
@@ -102,7 +112,10 @@ def compose(*behaviors: Behavior) -> Behavior:
         masks AND together; spawn masks OR together with the later
         behavior's child winning contested slots.  Behavior 0 receives the
         step key unchanged (bit-exact single-behavior parity); behavior
-        ``i>0`` receives ``fold_in(key, i)``.
+        ``i>0`` receives ``fold_in(key, i)``.  The composition takes
+        per-agent keys (``agent_keys``) only where every sub-behavior
+        does; otherwise every sub-behavior gets the per-device key, and a
+        per-agent one then draws by global id, but per device.
       * **params** — each sub-kernel closes over its own params; the merged
         ``params`` dict (namespaced the same way) is carried for
         introspection only.
@@ -168,10 +181,69 @@ def compose(*behaviors: Behavior) -> Behavior:
     return Behavior(
         schema=schema, pair_fn=pair, pair_attrs=pair_attrs,
         update_fn=update, radius=radius, params=params,
-        can_spawn=can_spawn, acc_spec=acc_spec, children=behs)
+        can_spawn=can_spawn, acc_spec=acc_spec, children=behs,
+        agent_keys=all(b.agent_keys for b in behs))
 
 
 Behavior.stack = staticmethod(compose)
+
+
+# Per-agent draws, on key words of the slot grid's shape.  Each is the
+# draw ``jax.random`` makes from one agent's key, bit for bit (the
+# counter-based threefry split; tests/test_agent_draws.py pins them), but
+# computed on one uint32 array per key word: a batched key array of shape
+# (..., 2) would put its two words on the chip's lane axis.
+
+def _block(words, data):
+    """The threefry-2x32 block of key ``words`` on the counter
+    ``(0, data)``: the key ``fold_in(key, data)``, which is also
+    ``split(key)[data]``."""
+    k0, k1 = words
+    d = jnp.broadcast_to(jnp.asarray(data).astype(jnp.uint32), k0.shape)
+    return tuple(threefry2x32_p.bind(k0, k1, jnp.zeros_like(d), d))
+
+
+def per_agent_keys(key: Array, attrs: Dict[str, Array]) -> Tuple[Array, Array]:
+    """Each agent slot's key ``fold_in(fold_in(key, gid_rank), gid_count)``
+    as two uint32 word arrays of the slot grid's shape.  With the step key
+    of an ``agent_keys`` behavior an agent's draws in step ``t`` are a
+    function of the engine's root key, ``t`` and its global id alone.
+    A behavior traces its draws under the ``sim.update.draws`` scope."""
+    rank = attrs[GID_RANK]
+    words = tuple(jnp.broadcast_to(key[i], rank.shape) for i in (0, 1))
+    return _block(_block(words, rank), attrs[GID_COUNT])
+
+
+def split_agent_keys(words, i: int) -> Tuple[Array, Array]:
+    """``split(key)[i]`` of every agent's key."""
+    return _block(words, i)
+
+
+def _bits(words, n: int) -> Array:
+    """``random_bits(key, 32, (n,))`` of every agent's key: shape
+    ``words[0].shape + (n,)``."""
+    k0, k1 = (jnp.broadcast_to(w[..., None], w.shape + (n,)) for w in words)
+    y0, y1 = _block((k0, k1), jax.lax.broadcasted_iota(jnp.int32, k0.shape,
+                                                       k0.ndim - 1))
+    return y0 ^ y1
+
+
+def agent_uniform(words, n: int, minval=0.0, maxval=1.0) -> Array:
+    """``uniform(key, (n,), float32, minval, maxval)`` of every agent's
+    key: shape ``words[0].shape + (n,)``."""
+    bits = _bits(words, n) >> jnp.uint32(9) | jnp.uint32(0x3F800000)
+    floats = jax.lax.bitcast_convert_type(bits, jnp.float32) \
+        - jnp.float32(1.0)
+    lo, hi = jnp.float32(minval), jnp.float32(maxval)
+    return jnp.maximum(lo, floats * (hi - lo) + lo)
+
+
+def agent_normal(words, n: int) -> Array:
+    """``normal(key, (n,), float32)`` of every agent's key: shape
+    ``words[0].shape + (n,)``."""
+    lo = np.nextafter(np.float32(-1.0), np.float32(0.0))
+    u = agent_uniform(words, n, lo, 1.0)
+    return jnp.float32(np.sqrt(2)) * jax.lax.erf_inv(u)
 
 
 # ---------------------------------------------------------------------------

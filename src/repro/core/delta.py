@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.compile_cache import memoize
+from repro.core.domain import wrap_torus
 
 Array = jax.Array
 
@@ -209,10 +210,7 @@ def decode_migration(payload: Slab, pos_name: str, half_range,
     out = dict(payload)
     center = out.pop(pos_name + "/center")
     p = center + out[pos_name].astype(jnp.float32) * scale
-    if any(toroidal):
-        L = jnp.asarray(lsz, jnp.float32)
-        p = jnp.where(jnp.asarray(toroidal), jnp.mod(p, L), p)
-    out[pos_name] = p
+    out[pos_name] = wrap_torus(p, lsz, toroidal)
     return out
 
 

@@ -49,6 +49,23 @@ def spatial_axis_names(ndim: int) -> Tuple[str, ...]:
     return tuple("s" + AXIS_CHARS[a] for a in range(ndim))
 
 
+def wrap_torus(p: Array, lsz, toroidal: Sequence[bool]) -> Array:
+    """Positions ``p`` (..., ndim) onto the half-open ``[0, L)`` of every
+    toroidal axis; closed axes pass through.
+
+    ``jnp.mod(p, L)`` alone rounds a float32 ``p`` in ``(-ulp(L)/2, 0)`` up
+    to exactly ``L`` (every ``p`` in ``(-3.05e-5, 0)`` at ``L = 1024``):
+    the agent then bins into the high ring cell and the next aura rebuild
+    clears it.  ``L`` is the same point of the torus as ``0``, so it folds
+    there."""
+    if not any(toroidal):
+        return p
+    L = jnp.asarray(lsz, jnp.float32)
+    q = jnp.mod(p, L)
+    q = jnp.where(q >= L, q - L, q)
+    return q if all(toroidal) else jnp.where(jnp.asarray(toroidal), q, p)
+
+
 def _as_int_tuple(x) -> Tuple[int, ...]:
     if isinstance(x, int):
         return (x,)

@@ -35,7 +35,7 @@ from repro.core.compile_cache import memoize
 from repro.core.delta import (
     DeltaConfig, Slab, decode_migration, encode_migration,
 )
-from repro.core.domain import Domain, spatial_axis_names
+from repro.core.domain import Domain, spatial_axis_names, wrap_torus
 from repro.core.grid import (
     bin_agents,
     bin_agents_jit,
@@ -481,8 +481,17 @@ class Engine:
             if owned is not None:
                 int_valid = int_valid \
                     & owned_mask(geom, owned)[isl][..., None]
-            step_key = jax.random.fold_in(
-                jax.random.fold_in(key, it), lrank)
+            if beh.agent_keys:
+                # the root is device 0's key, shared over the mesh (for a
+                # fresh state split(PRNGKey(seed), n)[0], the same for
+                # every n: JAX's threefry split is counter-based; a
+                # re-shard or restore re-roots it from the iteration)
+                root = comm.sum_over_all_ranks(
+                    jnp.where(lrank == 0, key, jnp.zeros_like(key)))
+                step_key = jax.random.fold_in(root, it)
+            else:
+                step_key = jax.random.fold_in(
+                    jax.random.fold_in(key, it), lrank)
             new_attrs, alive, spawn, child_attrs = beh.update_fn(
                 int_attrs, int_valid, acc, step_key, beh.params, self.dt
             )
@@ -622,12 +631,7 @@ class Engine:
         def wrap_pos(slab: Slab) -> Slab:
             if not any(tor):
                 return slab
-            out = dict(slab)
-            p = slab[POS]
-            wrapped = jnp.mod(p, lsz)
-            out[POS] = wrapped if all(tor) else jnp.where(
-                jnp.asarray(tor), wrapped, p)
-            return out
+            return {**slab, POS: wrap_torus(slab[POS], lsz, tor)}
 
         def ship(slab: Slab, axis: int, dirn: int):
             """One ring hop of a widened face, through the position codec

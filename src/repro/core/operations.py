@@ -13,11 +13,16 @@ operation reads correctly on one device and on a multi-pod mesh.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 from typing import Any, Callable, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core.compile_cache import memoize
 
 
 @dataclasses.dataclass
@@ -43,6 +48,23 @@ class Operation:
             return False
         return (tick % self.every == 0) if self.pre \
             else ((tick + 1) % self.every == 0)
+
+
+# The name of the scheduled operation running now (Simulation._run_op),
+# so a compiled reducer names its device scope after the operation's host
+# span ``sim.op.<name>``.
+_RUNNING: contextvars.ContextVar = contextvars.ContextVar(
+    "running_operation", default=None)
+
+
+@contextlib.contextmanager
+def running(name: str):
+    """Mark ``name`` as the scheduled operation running inside."""
+    token = _RUNNING.set(name)
+    try:
+        yield
+    finally:
+        _RUNNING.reset(token)
 
 
 # ---------------------------------------------------------------------------
@@ -90,16 +112,32 @@ def attr_mean(attr: str, name: str = "") -> Callable:
     return op
 
 
+@memoize("operations.counts", maxsize=64)
+def _counts_program(values: Tuple[int, ...], scope: str) -> Callable:
+    """One compiled reduction of ``(attr, valid)`` to the live count of
+    each value, its ops under the device scope ``scope``."""
+
+    def counts(a, valid):
+        with jax.named_scope(scope):
+            return jnp.stack([jnp.sum((a == v) & valid, dtype=jnp.int32)
+                              for v in values])
+
+    return jax.jit(counts)
+
+
 def attr_counts(attr: str, values: Sequence[int],
                 name: str = "") -> Callable:
     """Per-value occupation counts of an integer attribute (e.g. SIR state
-    compartments) over all live agents, all ranks."""
-    vals = tuple(values)
+    compartments) over all live agents, all ranks: one compiled reduction
+    and one transfer to the host a call, its ops under the device scope
+    ``sim.op.<name>`` of the scheduled operation that runs it."""
+    vals = tuple(int(v) for v in values)
 
     def op(sim) -> Tuple[int, ...]:
+        prog = _counts_program(vals, f"sim.op.{_RUNNING.get() or op.__name__}")
         soa = sim.state.soa
-        a = soa.attrs[attr]
-        return tuple(int(jnp.sum((a == v) & soa.valid)) for v in vals)
+        return tuple(int(c) for c in
+                     jax.device_get(prog(soa.attrs[attr], soa.valid)))
 
     op.__name__ = name or f"counts_{attr}"
     return op

@@ -47,7 +47,7 @@ from repro.core.engine import (
 )
 from repro.core.guards import GuardConfig, as_guard_config, check_health, \
     health_counts
-from repro.core.operations import Operation, checkpoint_op
+from repro.core.operations import Operation, checkpoint_op, running
 from repro.core.reshard import Rebalancer, estimate_device_runtimes
 
 # Geometry defaults applied when the first argument is a kwargs dict
@@ -514,7 +514,7 @@ class Simulation:
         return self
 
     def _run_op(self, op: Operation) -> None:
-        with TraceAnnotation(f"sim.op.{op.name}"):
+        with TraceAnnotation(f"sim.op.{op.name}"), running(op.name):
             value = op.fn(self)
         if op.record and value is not None:
             self.series.setdefault(op.name, []).append(value)

@@ -181,7 +181,5 @@ def migration_pos_decode_kernel(q, center, scale, *, lsz=None, toroidal=(),
         interpret=interpret,
     )(q, center.astype(jnp.float32).reshape(1, d),
       scale.astype(jnp.float32).reshape(1, d))
-    if any(toroidal):
-        L = jnp.asarray(lsz, jnp.float32)
-        pos = jnp.where(jnp.asarray(toroidal), jnp.mod(pos, L), pos)
-    return pos
+    from repro.core.domain import wrap_torus  # core imports the kernels
+    return wrap_torus(pos, lsz, toroidal)

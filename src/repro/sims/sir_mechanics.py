@@ -38,7 +38,8 @@ def behavior(repulsion=2.0, adhesion=0.5, mech_radius=2.0, max_step=0.3,
         repulsion=repulsion, adhesion=adhesion, radius=mech_radius,
         max_step=max_step)
     sir = epidemiology.behavior(
-        beta=beta, gamma=gamma, sigma=sigma, radius=sir_radius)
+        beta=beta, gamma=gamma, sigma=sigma, radius=sir_radius,
+        draws="slot")
     return compose(mech, sir)
 
 
@@ -129,7 +130,7 @@ def ensemble_behavior(params):
                 "same_type_only": 1.0,
                 "max_step": params["max_step"]})
     sir = dataclasses.replace(
-        epidemiology.behavior(radius=SIR_RADIUS_MAX),
+        epidemiology.behavior(radius=SIR_RADIUS_MAX, draws="slot"),
         pair_fn=_gated_sir_pair,
         params={"beta": params["beta"], "gamma": params["gamma"],
                 "sigma": params["sigma"],
